@@ -107,7 +107,7 @@ def measure(n_nodes: int, dim: int, requests: int, iters: int,
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.core.feature_cache import CacheConfig, init_cache_state
@@ -127,7 +127,7 @@ def measure(n_nodes: int, dim: int, requests: int, iters: int,
 
     # each worker fetches rows for ITS OWN stream, so the fetched block is
     # per-worker data — it must leave the shard_map sharded, not stamped
-    # replicated (check_rep=False would mask the mismatch silently)
+    # replicated (check_vma=False would mask the mismatch silently)
     if cached:
         def worker(t, i, c):
             c = jax.tree.map(lambda a: a[0], c)
@@ -139,7 +139,7 @@ def measure(n_nodes: int, dim: int, requests: int, iters: int,
 
         run = jax.jit(shard_map(
             worker, mesh=mesh, in_specs=(P("data"), P("data"), P("data")),
-            out_specs=(P("data"), P("data"), P("data")), check_rep=False))
+            out_specs=(P("data"), P("data"), P("data")), check_vma=False))
         state = jax.device_put(
             init_cache_state(cfg, dim, workers),
             NamedSharding(mesh, P("data")))
@@ -150,7 +150,7 @@ def measure(n_nodes: int, dim: int, requests: int, iters: int,
 
         run = jax.jit(shard_map(
             worker_nc, mesh=mesh, in_specs=(P("data"), P("data")),
-            out_specs=(P("data"), P("data")), check_rep=False))
+            out_specs=(P("data"), P("data")), check_vma=False))
         state = None
 
     table_j = jnp.asarray(table)

@@ -20,7 +20,7 @@ os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'
 import time
 import jax, jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.core.generation import Candidates, merge_topk
 from repro.core.tree_reduce import tree_allreduce
@@ -45,9 +45,9 @@ def flat(i, k):
     return jnp.take_along_axis(cand.ids, idx, axis=-1)
 
 run_tree = jax.jit(shard_map(tree, mesh=mesh, in_specs=(P('data'), P('data')),
-                             out_specs=P('data'), check_rep=False))
+                             out_specs=P('data'), check_vma=False))
 run_flat = jax.jit(shard_map(flat, mesh=mesh, in_specs=(P('data'), P('data')),
-                             out_specs=P('data'), check_rep=False))
+                             out_specs=P('data'), check_vma=False))
 for f in (run_tree, run_flat):
     jax.block_until_ready(f(ids, keys))
 out = {}

@@ -110,7 +110,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..graph.subgraph import SubgraphBatch
 from .feature_cache import (CacheConfig, CacheStats, FeatureCache,
@@ -123,7 +123,7 @@ from .feature_cache import (CacheConfig, CacheStats, FeatureCache,
                             unpack_hit_bitmap)
 from .host_store import HostFeatureStore, HostMissRequest
 from .partition import PartitionedGraph
-from .tree_reduce import axis_size, tree_allreduce, tree_reduce_scatter
+from .tree_reduce import tree_allreduce, tree_reduce_scatter
 
 
 class Candidates(NamedTuple):
@@ -883,7 +883,7 @@ def fetch_rows(
         raise ValueError('fetch_rows(store="device") requires table_local')
     if not host and host_admit is not None:
         raise ValueError('host_admit only applies to store="host"')
-    w = axis_size(axis_name)
+    w = lax.axis_size(axis_name)
     d = table_local.shape[1] if table_local is not None else feat_dim
     dtype = table_local.dtype if table_local is not None else jnp.float32
     rows = table_local.shape[0] if table_local is not None else 0
@@ -1375,7 +1375,7 @@ def make_generator_fn(
                 in_specs=(graph_spec, graph_spec, row_spec, graph_spec,
                           repl, P(axis_name), P(axis_name), P(axis_name)),
                 out_specs=_specs(P(axis_name), P(axis_name), P(axis_name)),
-                check_rep=False,
+                check_vma=False,
             )(indptr, indices, ys, seeds, rng, cache, admit_ids,
               admit_rows)
     elif host:
@@ -1387,7 +1387,7 @@ def make_generator_fn(
                 in_specs=(graph_spec, graph_spec, row_spec, graph_spec,
                           repl),
                 out_specs=_specs(P(axis_name), P(axis_name)),
-                check_rep=False,
+                check_vma=False,
             )(indptr, indices, ys, seeds, rng)
     elif cached and frozen:
         def gen_fn(device_args, seeds, rng, cache):
@@ -1398,7 +1398,7 @@ def make_generator_fn(
                 in_specs=(graph_spec, graph_spec, row_spec, row_spec,
                           graph_spec, repl, P(axis_name)),
                 out_specs=P(axis_name),
-                check_rep=False,
+                check_vma=False,
             )(indptr, indices, xs, ys, seeds, rng, cache)
     elif cached:
         def gen_fn(device_args, seeds, rng, cache):
@@ -1409,7 +1409,7 @@ def make_generator_fn(
                 in_specs=(graph_spec, graph_spec, row_spec, row_spec,
                           graph_spec, repl, P(axis_name)),
                 out_specs=_specs(P(axis_name), P(axis_name)),
-                check_rep=False,
+                check_vma=False,
             )(indptr, indices, xs, ys, seeds, rng, cache)
     else:
         def gen_fn(device_args, seeds, rng):
@@ -1421,7 +1421,7 @@ def make_generator_fn(
                           graph_spec, repl),
                 out_specs=(_specs(P(axis_name)) if collect_stats
                            else P(axis_name)),
-                check_rep=False,
+                check_vma=False,
             )(indptr, indices, xs, ys, seeds, rng)
 
     return gen_fn

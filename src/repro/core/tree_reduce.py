@@ -27,18 +27,6 @@ from jax import lax
 T = TypeVar("T")
 
 
-def axis_size(axis_name: str) -> int:
-    """Static size of a mapped axis.
-
-    ``lax.axis_size`` only exists in newer jax; ``lax.psum`` of a python
-    literal is position-invariant, so jax returns it as a static int on
-    every version this repo supports.
-    """
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
 def tree_allreduce(
     x: T,
     merge: Callable[[T, T], T],
@@ -46,7 +34,7 @@ def tree_allreduce(
 ) -> T:
     """Butterfly allreduce of pytree ``x`` along ``axis_name`` (size must be
     a power of two — mesh axes here are 2/16) using ``merge`` at each stage."""
-    size = axis_size(axis_name)
+    size = lax.axis_size(axis_name)
     if size & (size - 1):
         raise ValueError(f"butterfly needs power-of-two axis, got {size}")
     stage = 1
@@ -83,7 +71,7 @@ def tree_reduce_scatter(
     by the axis size); returns the fully-merged rows ``me*F/W : (me+1)*F/W``
     for each worker (big-endian rank-bit segment ordering).
     """
-    size = axis_size(axis_name)
+    size = lax.axis_size(axis_name)
     if size & (size - 1):
         raise ValueError(f"recursive halving needs power-of-two axis, got {size}")
     me = lax.axis_index(axis_name)

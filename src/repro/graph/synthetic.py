@@ -12,6 +12,10 @@ import numpy as np
 
 from .csr import CSRGraph
 
+#: default share of the base edge count that the planted hubs hold
+#: together, when no ``hot_degree`` is given
+HUB_EDGE_SHARE = 0.25
+
 
 def powerlaw_graph(
     n_nodes: int,
@@ -24,7 +28,11 @@ def powerlaw_graph(
     """Directed power-law graph via a configuration model.
 
     ``n_hot`` nodes are planted with out-degree ``hot_degree`` to stress the
-    hot-node aggregation path (paper §2 step 3).
+    hot-node aggregation path (paper §2 step 3).  Without a ``hot_degree``
+    the hubs share ``HUB_EDGE_SHARE * n_nodes * avg_degree`` edges and the
+    natural degrees are clipped just below theirs, so the hubs keep the
+    largest degrees and the edge count stays within 1.25x the base graph's
+    at any scale.
     """
     rng = np.random.default_rng(seed)
     # Zipf-ish degrees clipped so the expected mean is ~avg_degree.
@@ -33,7 +41,11 @@ def powerlaw_graph(
     deg = np.maximum((raw * (avg_degree / raw.mean())).astype(np.int64), 1)
     if n_hot > 0:
         hot_ids = rng.choice(n_nodes, size=n_hot, replace=False)
-        deg[hot_ids] = hot_degree or max(int(deg.max() * 10), 100)
+        if not hot_degree:
+            hot_degree = max(int(HUB_EDGE_SHARE * n_nodes * avg_degree
+                                 / n_hot), 2)
+            deg = np.minimum(deg, hot_degree - 1)
+        deg[hot_ids] = hot_degree
     src = np.repeat(np.arange(n_nodes, dtype=np.int32), deg)
     dst = rng.integers(0, n_nodes, size=len(src), dtype=np.int32)
     return CSRGraph.from_edges(src, dst, n_nodes)

@@ -96,7 +96,7 @@ def flash_attention_pallas(
 
     kwargs = {}
     if pltpu is not None and not interpret:
-        kwargs["compiler_params"] = pltpu.TPUCompilerParams(
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         )
     out = pl.pallas_call(

@@ -17,7 +17,7 @@ from .cache_gather import (cache_probe_compact_pallas,
                            cache_probe_gather_pallas,
                            cache_probe_tiered_pallas)
 from .flash_attention import flash_attention_pallas
-from .gather_reduce import fanout_mean_pallas, gather_reduce_pallas
+from .fanout_mean import fanout_mean_pallas
 from .ssd_scan import ssd_scan_pallas
 
 
@@ -31,16 +31,6 @@ def fanout_mean(x: jax.Array, mask: jax.Array, use_kernel: bool = False) -> jax.
     if use_kernel:
         return fanout_mean_pallas(x, mask, interpret=_interpret())
     return ref.fanout_mean_ref(x, mask)
-
-
-def gather_reduce(
-    table: jax.Array, idx: jax.Array, mask: jax.Array, use_kernel: bool = False
-) -> jax.Array:
-    """Fused gather + masked mean: table [N, D], idx/mask [M, K] -> [M, D]
-    (the per-worker hot spot of edge-centric collection + aggregation)."""
-    if use_kernel:
-        return gather_reduce_pallas(table, idx, mask, interpret=_interpret())
-    return ref.gather_reduce_ref(table, idx, mask)
 
 
 def cache_probe_gather(

@@ -20,16 +20,6 @@ def fanout_mean_ref(x: jax.Array, mask: jax.Array) -> jax.Array:
     return num / den
 
 
-def gather_reduce_ref(
-    table: jax.Array, idx: jax.Array, mask: jax.Array
-) -> jax.Array:
-    """Gather rows then masked-mean: table [N, D], idx [M, K], mask [M, K]
-    -> [M, D].  The fused per-worker hot spot of edge-centric collection +
-    aggregation."""
-    rows = table[jnp.clip(idx, 0, table.shape[0] - 1)]        # [M, K, D]
-    return fanout_mean_ref(rows, mask)
-
-
 def cache_probe_gather_ref(
     keys: jax.Array, rows: jax.Array, ids: jax.Array, assoc: int = 1
 ) -> tuple:

@@ -73,7 +73,7 @@ def ssd_scan_pallas(
     grid = (bsz, h, pl.cdiv(l, q))
     kwargs = {}
     if pltpu is not None and not interpret:
-        kwargs["compiler_params"] = pltpu.TPUCompilerParams(
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         )
     return pl.pallas_call(

@@ -55,6 +55,7 @@ from ..graph.synthetic import (node_features, node_labels,  # noqa: E402
 from ..models import gcn as gcn_mod                     # noqa: E402
 from ..models import zoo                                # noqa: E402
 from ..train import checkpoint as ckpt                  # noqa: E402
+from .compile_cache import enable_compile_cache        # noqa: E402
 from .mesh import make_mesh                             # noqa: E402
 
 #: default request-shape ladder: per-worker seed slots per bucket.  Small
@@ -321,9 +322,6 @@ def serve_gcn(args) -> dict:
     print(f"served {len(latencies)} requests in {wall:.2f}s "
           f"({qps:.1f} req/s): p50 {p50:.2f}ms p99 {p99:.2f}ms, "
           f"{request_compiles} request-path compiles")
-    if request_compiles:
-        print("WARNING: requests landed on uncompiled shapes — the "
-              "bucket ladder does not cover the request stream")
     return {"p50_ms": float(p50), "p99_ms": float(p99), "qps": float(qps),
             "n_requests": len(latencies), "wall_s": float(wall),
             "request_path_compiles": int(request_compiles),
@@ -417,8 +415,14 @@ def main() -> None:
                          "checkpoint dir (train.py --export-serve) "
                          "instead of sweeping")
     args = ap.parse_args()
+    enable_compile_cache()
     if get_config(args.arch).family == "gcn":
-        serve_gcn(args)
+        rec = serve_gcn(args)
+        if rec["request_path_compiles"]:
+            raise SystemExit(
+                f"{rec['request_path_compiles']} requests compiled on the "
+                f"request path: the bucket ladder does not cover the "
+                f"request stream")
     else:
         serve_lm(args)
 

@@ -42,6 +42,7 @@ from ..models import zoo                                # noqa: E402
 from ..train import checkpoint as ckpt                  # noqa: E402
 from ..train.optimizer import adam_update, init_adam    # noqa: E402
 from ..train.train_loop import init_state, make_train_step  # noqa: E402
+from .compile_cache import enable_compile_cache        # noqa: E402
 from .mesh import make_mesh                             # noqa: E402
 
 
@@ -178,7 +179,15 @@ def warm_capacity(miss_peak: int, w: int, slack: float, rows: int,
 
 
 def train_gcn(args) -> dict:
+    """Train the GCN of ``args.arch`` on a synthetic power-law graph.
+
+    Returns the losses, padded nodes per iteration, wall and set-up
+    seconds, the slack used and the requests dropped over every trained
+    batch; on the device feature store also the last trained batch with
+    the seeds and rng that generated it, the placed ``device_args`` and
+    the mesh."""
     import dataclasses
+    t_setup = time.perf_counter()
     w = args.workers
     mesh = make_mesh((w,), ("data",))
     cfg = get_config(args.arch)
@@ -394,6 +403,7 @@ def train_gcn(args) -> dict:
         batch = gen_fn(device_args, seeds_for(start), rngs[start])
         carry = (params, opt, batch)
     losses = []
+    n_dropped = 0             # requests dropped over every trained batch
     miss_peak = 0
     wide_step = None          # pre-recalibration step, kept for rollback
     # the first batches carry the cold-start miss burst the cache exists to
@@ -426,6 +436,7 @@ def train_gcn(args) -> dict:
             print(f"step {t}: shrunken capacity dropped requests — "
                   f"regenerated the batch and rolled back to the "
                   f"calibrated width")
+        n_dropped += int(np.asarray(carry[2].n_dropped).sum())
         if (args.warm_recalibrate and cached and w > 1
                 and t == start + args.warm_recalibrate
                 and t + 1 < args.steps):
@@ -518,7 +529,15 @@ def train_gcn(args) -> dict:
     dt = time.perf_counter() - t0
     nodes_per_iter = batch.nodes_per_iteration()
     out = {"losses": losses, "nodes_per_iter": nodes_per_iter, "wall_s": dt,
-           "capacity_slack": slack}
+           "capacity_slack": slack, "n_dropped": n_dropped,
+           "setup_s": t0 - t_setup}
+    if not host:
+        # the last trained batch and what generated it, for checks that
+        # regenerate it (host-mode batches hold staged holes instead)
+        last = max(args.steps - 1, start)
+        out.update(batch=carry[2], batch_seeds=seeds_for(last),
+                   batch_rng=rngs[last], device_args=device_args,
+                   mesh=mesh)
     if host:
         out["host_gather_mb"] = store.bytes_issued / 1e6
         print(f"L3 host gathers shipped {out['host_gather_mb']:.1f} MB "
@@ -577,7 +596,9 @@ def train_lm(args) -> dict:
     return {"losses": losses, "wall_s": dt}
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
+    """The launcher's command line (``main`` parses ``sys.argv`` with it;
+    scripts that drive ``train_gcn`` parse their own argument lists)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="graphgen-gcn")
     ap.add_argument("--fanouts", default=None,
@@ -665,7 +686,12 @@ def main() -> None:
                     help="after training, checkpoint params + the warm "
                          "cache state for the serving tier "
                          "(repro.launch.serve --warm-from DIR)")
-    args = ap.parse_args()
+    return ap
+
+
+def main() -> None:
+    args = build_parser().parse_args()
+    enable_compile_cache()
     if args.cache_probe_impl != "jnp":
         from ..core.feature_cache import set_probe_impl
         set_probe_impl(args.cache_probe_impl)

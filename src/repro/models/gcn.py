@@ -10,9 +10,9 @@ neighbor term at the seed's first layer).  After layer L only the seed
 level remains.
 
 Aggregation on a padded fanout tree is a masked mean over the fanout axis
-followed by a dense transform — the masked mean is the `gather_reduce`
-Pallas kernel's job on TPU (kernels/gather_reduce.py); here we route through
-``kernels.ops.fanout_mean`` which picks kernel vs reference implementation.
+followed by a dense transform — the masked mean routes through
+``kernels.ops.fanout_mean``, which picks the Pallas kernel
+(kernels/fanout_mean.py) or the reference implementation.
 """
 from __future__ import annotations
 

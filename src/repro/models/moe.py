@@ -64,7 +64,7 @@ def moe_forward_ep(p: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
                   einsum [E_loc, C, D] x [E_loc, D, F].
       4. a2a back + weighted combine.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = L.get_mesh()
@@ -142,7 +142,7 @@ def moe_forward_ep(p: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
         in_specs=(P(), P("model", None, None), P("model", None, None),
                   P("model", None, None), P(dpa, "model", None)),
         out_specs=P(dpa, "model", None),
-        check_rep=False,
+        check_vma=False,
     )(p["router"], p["wg"], p["wu"], p["wd"], x)
     if "shared" in p:
         y = y + L.mlp_forward(p["shared"], x.reshape(b * s, d)).reshape(b, s, d)

@@ -16,7 +16,7 @@ from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..core.config import TrainConfig
@@ -95,7 +95,7 @@ def make_shardmap_grad_sync(mesh: Mesh, axis_name: str = "data"):
 
         specs = jax.tree.map(lambda _: P(), grads)
         return shard_map(
-            inner, mesh=mesh, in_specs=(specs,), out_specs=specs, check_rep=False
+            inner, mesh=mesh, in_specs=(specs,), out_specs=specs, check_vma=False
         )(grads)
 
     return sync

@@ -96,7 +96,7 @@ def test_hot_node_sampling_is_unbiased_across_partitions():
 def test_tree_allreduce_matches_psum():
     out = run_forced("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.core.tree_reduce import tree_psum
         from repro.launch.mesh import make_mesh
@@ -105,10 +105,10 @@ def test_tree_allreduce_matches_psum():
         x = jnp.arange(8 * 5, dtype=jnp.float32).reshape(8, 5)
         tree = shard_map(lambda v: tree_psum(v, "data"), mesh=mesh,
                          in_specs=P("data"), out_specs=P("data"),
-                         check_rep=False)(x)
+                         check_vma=False)(x)
         flat = shard_map(lambda v: jax.lax.psum(v, "data"), mesh=mesh,
                          in_specs=P("data"), out_specs=P("data"),
-                         check_rep=False)(x)
+                         check_vma=False)(x)
         np.testing.assert_allclose(np.asarray(tree), np.asarray(flat))
         print("TREE_OK")
     """)
@@ -118,7 +118,7 @@ def test_tree_allreduce_matches_psum():
 def test_fetch_rows_multiworker_routes_correctly():
     out = run_forced("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.core.generation import fetch_rows
         from repro.launch.mesh import make_mesh
@@ -130,7 +130,7 @@ def test_fetch_rows_multiworker_routes_correctly():
         ids = rng.integers(0, W * rows, size=64).astype(np.int32)
         out = shard_map(lambda t, i: fetch_rows(t, i, "data"),
                         mesh=mesh, in_specs=(P("data"), P()), out_specs=P(),
-                        check_rep=False)(jnp.asarray(table), jnp.asarray(ids))
+                        check_vma=False)(jnp.asarray(table), jnp.asarray(ids))
         np.testing.assert_array_equal(np.asarray(out), table[ids])
         print("FETCH_OK")
     """)
@@ -145,7 +145,7 @@ def test_fetch_rows_skew_reports_drops_and_dedup_avoids_them():
     capacity == n_unique."""
     out = run_forced("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.core.generation import fetch_rows
         from repro.launch.mesh import make_mesh
@@ -162,7 +162,7 @@ def test_fetch_rows_skew_reports_drops_and_dedup_avoids_them():
                 lambda t, i: fetch_rows(t, i, "data", dedup=dedup,
                                         capacity=capacity, return_stats=True),
                 mesh=mesh, in_specs=(P("data"), P()), out_specs=P(),
-                check_rep=False)(jnp.asarray(table), jnp.asarray(ids))
+                check_vma=False)(jnp.asarray(table), jnp.asarray(ids))
 
         n_unique = len(np.unique(ids))
         assert n_unique == 16
@@ -194,7 +194,7 @@ def test_fetch_rows_shard_boundary_ids_route_correctly():
     edges is exactly where an off-by-one would hide."""
     out = run_forced("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.core.generation import fetch_rows
         from repro.launch.mesh import make_mesh
@@ -208,7 +208,7 @@ def test_fetch_rows_shard_boundary_ids_route_correctly():
         out, stats = shard_map(
             lambda t, i: fetch_rows(t, i, "data", return_stats=True),
             mesh=mesh, in_specs=(P("data"), P()), out_specs=P(),
-            check_rep=False)(jnp.asarray(table), jnp.asarray(ids))
+            check_vma=False)(jnp.asarray(table), jnp.asarray(ids))
         np.testing.assert_array_equal(np.asarray(out), table[ids])
         assert int(stats.n_unique) == len(set(edges))
         assert int(stats.n_dropped) == 0
@@ -416,7 +416,7 @@ def test_host_fetch_conservation_empty_and_all_miss():
     ``l1 + local + shard + l3 + misses == distinct`` per worker."""
     out = run_forced("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.core.feature_cache import CacheConfig, init_cache_state
         from repro.core.generation import fetch_rows
@@ -438,7 +438,7 @@ def test_host_fetch_conservation_empty_and_all_miss():
             return jax.jit(shard_map(
                 worker, mesh=mesh,
                 in_specs=(P("data"),) * 4, out_specs=(P("data"),) * 4,
-                check_rep=False))
+                check_vma=False))
 
         for mode in ("replicated", "sharded", "tiered"):
             cfg = CacheConfig(32, admit=1, assoc=2, mode=mode,
@@ -508,7 +508,7 @@ def test_cached_fetch_all_modes_bit_identical_w4():
     l1 + local + shard + misses == distinct holds per worker."""
     out = run_forced("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.core.feature_cache import CacheConfig, init_cache_state
         from repro.core.generation import fetch_rows
@@ -538,7 +538,7 @@ def test_cached_fetch_all_modes_bit_identical_w4():
                 worker, mesh=mesh,
                 in_specs=(P("data"), P("data"), P("data")),
                 out_specs=(P("data"), P("data"), P("data")),
-                check_rep=False))
+                check_vma=False))
             state = jax.device_put(init_cache_state(cfg, d, W), spec)
             rng = np.random.default_rng(trial)
             total_hits = total_l1 = 0
@@ -578,7 +578,7 @@ def test_sharded_cache_beats_replicated_capacity():
     hit population appears."""
     out = run_forced("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.core.feature_cache import CacheConfig, init_worker_caches
         from repro.core.generation import fetch_rows
@@ -610,7 +610,7 @@ def test_sharded_cache_beats_replicated_capacity():
                 worker, mesh=mesh,
                 in_specs=(P("data"), P("data"), P("data")),
                 out_specs=(P("data"), P("data"), P("data")),
-                check_rep=False))
+                check_vma=False))
             state = jax.device_put(init_worker_caches(c, d, W), spec)
             hits = shard_hits = 0
             for ids in streams:

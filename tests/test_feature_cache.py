@@ -505,7 +505,7 @@ def test_l1_promotion_requires_repeat_observations():
     """The L2 -> L1 migration gate: with l1_promote=2, one observation of
     an L2-served row only tracks it in the L1; the second installs it —
     after which the id is served with zero network (an L1 hit)."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.launch.mesh import make_local_mesh
 
@@ -525,7 +525,7 @@ def test_l1_promotion_requires_repeat_observations():
 
     run = jax.jit(shard_map(
         worker, mesh=mesh, in_specs=(P(), P(), P("data")),
-        out_specs=(P(), P("data"), P("data")), check_rep=False))
+        out_specs=(P(), P("data"), P("data")), check_vma=False))
     state = jax.tree.map(jnp.asarray, init_cache_state(cfg, d, 1))
     ids = jnp.asarray(np.arange(10, dtype=np.int32))
     l1_hits = []
@@ -552,7 +552,7 @@ def test_hit_conservation_invariant_adversarial_streams(mode):
     all-distinct, single-id, and the empty batch.  Each stream runs cold
     AND warm (the warm pass moves population between the categories; the
     sum must not move), and rows stay bit-identical throughout."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.launch.mesh import make_local_mesh
 
@@ -566,7 +566,7 @@ def test_hit_conservation_invariant_adversarial_streams(mode):
     if cfg is None:
         run = jax.jit(shard_map(
             lambda t, i: fetch_rows(t, i, "data", return_stats=True),
-            mesh=mesh, in_specs=(P(), P()), out_specs=P(), check_rep=False))
+            mesh=mesh, in_specs=(P(), P()), out_specs=P(), check_vma=False))
         state = None
     else:
         def worker(t, i, c):
@@ -578,7 +578,7 @@ def test_hit_conservation_invariant_adversarial_streams(mode):
 
         run = jax.jit(shard_map(
             worker, mesh=mesh, in_specs=(P(), P(), P("data")),
-            out_specs=(P(), P("data"), P("data")), check_rep=False))
+            out_specs=(P(), P("data"), P("data")), check_vma=False))
         state = jax.tree.map(jnp.asarray, init_cache_state(cfg, d, 1))
     streams = [
         np.full(64, 7, np.int32),          # all-duplicate
@@ -625,7 +625,7 @@ def _fetch_fn(kind, admit=1, assoc=1, dedup=True):
     key = (kind, admit, assoc, dedup)
     if key in _FETCH_FNS:
         return _FETCH_FNS[key]
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.launch.mesh import make_local_mesh
 
@@ -633,7 +633,7 @@ def _fetch_fn(kind, admit=1, assoc=1, dedup=True):
     if kind == "plain":
         fn = jax.jit(shard_map(
             lambda t, i: fetch_rows(t, i, "data", dedup=dedup),
-            mesh=mesh, in_specs=(P(), P()), out_specs=P(), check_rep=False))
+            mesh=mesh, in_specs=(P(), P()), out_specs=P(), check_vma=False))
     else:
         def worker(t, i, c):
             cfg = CacheConfig(
@@ -646,7 +646,7 @@ def _fetch_fn(kind, admit=1, assoc=1, dedup=True):
 
         fn = jax.jit(shard_map(
             worker, mesh=mesh, in_specs=(P(), P(), P("data")),
-            out_specs=(P(), P("data"), P("data")), check_rep=False))
+            out_specs=(P(), P("data"), P("data")), check_vma=False))
     _FETCH_FNS[key] = fn
     return fn
 
@@ -741,7 +741,7 @@ def test_cache_requires_cfg():
 def test_pallas_probe_impl_serves_cached_fetch():
     """set_probe_impl('pallas') routes the production fetch front end
     through the fused kernel — rows stay bit-identical to the table."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.core.feature_cache import set_probe_impl
     from repro.launch.mesh import make_local_mesh
@@ -763,7 +763,7 @@ def test_pallas_probe_impl_serves_cached_fetch():
     try:
         run = jax.jit(shard_map(
             worker, mesh=mesh, in_specs=(P(), P(), P("data")),
-            out_specs=(P(), P("data"), P("data")), check_rep=False))
+            out_specs=(P(), P("data"), P("data")), check_vma=False))
         cache = jax.tree.map(jnp.asarray, init_worker_caches(32, d, 1))
         _, cache, _ = run(table, ids, cache)
         got, cache, (fs, cs) = run(table, ids, cache)

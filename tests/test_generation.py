@@ -63,7 +63,7 @@ def test_merge_topk_associative(seed):
 
 
 def test_fetch_rows_single_worker_is_gather():
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.launch.mesh import make_local_mesh
 
@@ -72,7 +72,7 @@ def test_fetch_rows_single_worker_is_gather():
     ids = jnp.array([3, 19, 0, 7], dtype=jnp.int32)
     out = shard_map(
         lambda t, i: fetch_rows(t, i, "data"),
-        mesh=mesh, in_specs=(P(), P()), out_specs=P(), check_rep=False,
+        mesh=mesh, in_specs=(P(), P()), out_specs=P(), check_vma=False,
     )(table, ids)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(table)[np.asarray(ids)])
 
@@ -122,7 +122,7 @@ def test_dedup_requests_full_shard_range_routing():
     """Full-table-range ids dedup and fetch correctly at W=1 (the local-
     gather path with dedup telemetry; the ROUTED owner-bucketing version of
     this runs on 8 workers in test_distributed.py)."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.launch.mesh import make_local_mesh
 
@@ -132,7 +132,7 @@ def test_dedup_requests_full_shard_range_routing():
     ids = jnp.asarray([0, 159, 80, 0, 159, 42, 21, 21], jnp.int32)
     out, stats = shard_map(
         lambda t, i: fetch_rows(t, i, "data", return_stats=True),
-        mesh=mesh, in_specs=(P(), P()), out_specs=P(), check_rep=False,
+        mesh=mesh, in_specs=(P(), P()), out_specs=P(), check_vma=False,
     )(table, ids)
     np.testing.assert_array_equal(np.asarray(out),
                                   np.asarray(table)[np.asarray(ids)])
@@ -143,7 +143,7 @@ def test_dedup_requests_full_shard_range_routing():
 def test_fetch_rows_dedup_matches_naive_single_worker():
     """Shuffled duplicate ids must fetch identical rows via the dedup path
     and the naive path."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.launch.mesh import make_local_mesh
 
@@ -155,7 +155,7 @@ def test_fetch_rows_dedup_matches_naive_single_worker():
     def run(dedup):
         return shard_map(
             lambda t, i: fetch_rows(t, i, "data", dedup=dedup),
-            mesh=mesh, in_specs=(P(), P()), out_specs=P(), check_rep=False,
+            mesh=mesh, in_specs=(P(), P()), out_specs=P(), check_vma=False,
         )(table, ids)
 
     np.testing.assert_array_equal(np.asarray(run(True)), np.asarray(run(False)))

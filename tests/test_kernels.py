@@ -7,7 +7,7 @@ import pytest
 from repro.kernels import ref
 from repro.kernels.cache_gather import cache_probe_gather_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.gather_reduce import fanout_mean_pallas, gather_reduce_pallas
+from repro.kernels.fanout_mean import fanout_mean_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas
 
 
@@ -21,16 +21,6 @@ def test_fanout_mean(m, k, d, dtype):
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), rtol=tol, atol=tol)
-
-
-@pytest.mark.parametrize("n,d,m,k", [(100, 64, 13, 5), (64, 128, 32, 20), (257, 96, 8, 40)])
-def test_gather_reduce(n, d, m, k):
-    table = jax.random.normal(jax.random.PRNGKey(2), (n, d))
-    idx = jax.random.randint(jax.random.PRNGKey(3), (m, k), 0, n)
-    mask = jax.random.bernoulli(jax.random.PRNGKey(4), 0.8, (m, k))
-    got = gather_reduce_pallas(table, idx, mask)
-    want = ref.gather_reduce_ref(table, idx, mask)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("c,d,r", [(64, 32, 17), (256, 128, 300), (1024, 96, 64)])

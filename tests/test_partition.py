@@ -34,6 +34,34 @@ def test_edge_hash_splits_hot_nodes():
     assert max(local_deg) < g.degrees()[hot]       # nobody holds it all
 
 
+@pytest.mark.parametrize("n,avg", [(20_000, 10.0), (200_000, 25.0)])
+def test_default_hubs_keep_edge_count_bounded(n, avg):
+    """The launchers' hubs (n // 1000 of them, no ``hot_degree``) stay the
+    highest-degree nodes without multiplying the edge count at scale."""
+    n_hot = n // 1000
+    g = powerlaw_graph(n, avg_degree=avg, n_hot=n_hot, seed=0)
+    assert len(g.indices) <= 1.5 * n * avg
+    deg = np.sort(g.degrees())
+    assert deg[-n_hot] > deg[-n_hot - 1]
+
+
+def test_explicit_hot_degree_graph_unchanged():
+    """A caller's own ``hot_degree`` plants hubs on the unclipped base
+    degrees, exactly as the configuration model always has."""
+    n, avg, n_hot, hot, seed = 3000, 8.0, 3, 500, 4
+    rng = np.random.default_rng(seed)
+    raw = np.minimum(rng.zipf(2.1, size=n).astype(np.float64), n // 2)
+    deg = np.maximum((raw * (avg / raw.mean())).astype(np.int64), 1)
+    deg[rng.choice(n, size=n_hot, replace=False)] = hot
+    src = np.repeat(np.arange(n, dtype=np.int32), deg)
+    dst = rng.integers(0, n, size=len(src), dtype=np.int32)
+    want = CSRGraph.from_edges(src, dst, n)
+    g = powerlaw_graph(n, avg_degree=avg, n_hot=n_hot, hot_degree=hot,
+                       seed=seed)
+    np.testing.assert_array_equal(g.indptr, want.indptr)
+    np.testing.assert_array_equal(g.indices, want.indices)
+
+
 def test_edge_hash_balances_better_than_src_block():
     g = powerlaw_graph(2000, avg_degree=8, n_hot=5, hot_degree=400, seed=2)
     ph = partition_edges(g, 8, strategy="by_edge_hash")

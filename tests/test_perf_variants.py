@@ -117,7 +117,7 @@ def test_reduce_scatter_generation_matches_butterfly():
 def test_tree_reduce_scatter_segments():
     out = _run_forced("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.core.tree_reduce import tree_reduce_scatter
         from repro.launch.mesh import make_mesh
@@ -129,7 +129,7 @@ def test_tree_reduce_scatter_segments():
             return tree_reduce_scatter(
                 v[0], lambda a, b: a + b, "data")
         out = shard_map(body, mesh=mesh, in_specs=P("data"),
-                        out_specs=P("data"), check_rep=False)(x)
+                        out_specs=P("data"), check_vma=False)(x)
         # every row of every segment = sum over workers = 28
         np.testing.assert_array_equal(np.asarray(out),
                                       np.full((W * (F // W),), 28.0))

@@ -5,6 +5,7 @@ regression.  Multi-worker serve cells (the frozen differential matrix)
 run in test_distributed.py subprocesses."""
 import argparse
 import dataclasses
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -114,7 +115,7 @@ def test_frozen_fetch_cache_state_bit_stable(mode):
     (2) a cache state whose every leaf is BIT-identical to the input —
     no admission, no counter bumps, no L1 promotion — while still
     serving hits from the warm slots."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     rows_n, d = 64, 4
@@ -139,7 +140,7 @@ def test_frozen_fetch_cache_state_bit_stable(mode):
             worker, mesh=mesh,
             in_specs=(P("data"), P("data"), P("data")),
             out_specs=(P("data"), P("data"), P("data")),
-            check_rep=False))
+            check_vma=False))
 
     # warm under the MUTABLE config (repeat ids so admit=1 + promotion fire)
     run_mut = make_run(cfg)
@@ -223,6 +224,22 @@ def _lm_args(**over):
                 prompt_len=4, gen_len=3)
     base.update(over)
     return argparse.Namespace(**base)
+
+
+@pytest.mark.parametrize("compiles", [0, 2])
+def test_serve_main_fails_on_request_path_compiles(monkeypatch, compiles):
+    """A request that compiles on the request path fails the run."""
+    from repro.launch import serve
+    monkeypatch.setattr(serve, "enable_compile_cache", lambda: None)
+    monkeypatch.setattr(serve, "serve_gcn",
+                        lambda args: {"request_path_compiles": compiles})
+    monkeypatch.setattr(sys, "argv", ["serve", "--arch", "graphgen-gcn"])
+    if not compiles:
+        serve.main()
+        return
+    with pytest.raises(SystemExit) as exc:
+        serve.main()
+    assert exc.value.code and "request path" in str(exc.value.code)
 
 
 def test_serve_lm_prompt_len_zero_regression():
