@@ -125,6 +125,38 @@ from .host_store import HostFeatureStore, HostMissRequest
 from .partition import PartitionedGraph
 from .tree_reduce import tree_allreduce, tree_reduce_scatter
 
+#: The generator's stages, each a ``jax.named_scope`` around the code that
+#: does its work (``_stage``).  An operation belongs to the OUTERMOST stage
+#: in its name stack, so the stages partition the device time of
+#: ``jit(gen_fn)``: the label fetch's gather counts under ``labels``, not
+#: ``owner_fetch``.  Scopes are metadata only; the compiled instructions
+#: are the same without them.
+STAGES = ("frontier", "edge_scan", "tree_merge", "dedup", "cache_probe",
+          "owner_fetch", "cache_insert", "slot_scatter", "labels")
+
+
+def _stage(name: str):
+    """The named scope of generation stage ``name`` (one of ``STAGES``)."""
+    if name not in STAGES:
+        raise ValueError(f"unknown generation stage {name!r}; "
+                         f"expected one of {STAGES}")
+    return jax.named_scope(name)
+
+
+def _staged(name: str):
+    """Decorator: run the function inside ``_stage(name)``, entered anew
+    on each call (one scope object shared by every call keeps the name
+    stack it replaced on itself, so it is not re-entrant)."""
+    _stage(name)
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def run(*args, **kwargs):
+            with _stage(name):
+                return fn(*args, **kwargs)
+        return run
+    return wrap
+
 
 class Candidates(NamedTuple):
     ids: jax.Array    # [F, k] neighbor node ids
@@ -213,6 +245,7 @@ def merge_topk(a: Candidates, b: Candidates) -> Candidates:
     return Candidates(ids=jnp.take_along_axis(ids, idx, axis=-1), keys=-neg)
 
 
+@_staged("dedup")
 def dedup_requests(ids: jax.Array):
     """Static-shape sort+segment unique (``jnp.unique`` needs dynamic sizes).
 
@@ -510,12 +543,14 @@ class _ReplicatedTier:
     """mode="replicated": local probe, local admission."""
 
     @staticmethod
+    @_staged("cache_probe")
     def probe(cache, cfg, ids, valid, axis_name, cap, w):
         hit, rows = cache_probe(cache, ids, valid, cfg=cfg)
         return _TierProbe(hit, rows, _zeros_like_hits(ids), hit,
                           _no_wire(), ())
 
     @staticmethod
+    @_staged("cache_insert")
     def admit(cache, cfg, probe, ids, fetched, should, axis_name, w):
         return cache_insert(cache, ids, fetched, should, cfg)
 
@@ -526,6 +561,7 @@ class _ShardedTier:
     behavior (the single worker owns every shard)."""
 
     @staticmethod
+    @_staged("cache_probe")
     def probe(cache, cfg, ids, valid, axis_name, cap, w):
         if w == 1:
             hit, rows = cache_probe(cache, ids, valid, cfg=cfg)
@@ -539,6 +575,7 @@ class _ShardedTier:
                           (plan, recv))
 
     @staticmethod
+    @_staged("cache_insert")
     def admit(cache, cfg, probe, ids, fetched, should, axis_name, w):
         if w == 1:
             return cache_insert(cache, ids, fetched, should, cfg)
@@ -554,6 +591,7 @@ class _TieredTier:
     the requester's L1 (installed after ``l1_promote`` observations)."""
 
     @staticmethod
+    @_staged("cache_probe")
     def probe(cache, cfg, ids, valid, axis_name, cap, w):
         if w == 1:
             # single worker owns both tiers: the fused local probe (the
@@ -576,6 +614,7 @@ class _TieredTier:
                           local, wire, (plan, recv, l2_hit))
 
     @staticmethod
+    @_staged("cache_insert")
     def admit(cache, cfg, probe, ids, fetched, should, axis_name, w):
         plan, recv, l2_hit = probe.ctx
         if w == 1:
@@ -638,6 +677,7 @@ def _cache_tier(cfg: CacheConfig):
     return _FrozenTier(base) if cfg.frozen else base
 
 
+@_staged("cache_insert")
 def _host_admit(cache, cfg: CacheConfig, adm_ids: jax.Array,
                 adm_rows: jax.Array, axis_name: str, w: int):
     """Deferred admission: offer the PREVIOUS step's landed L3 rows.
@@ -715,25 +755,27 @@ def _host_fetch(ids, axis_name, capacity_slack, capacity, cache, cache_cfg,
         probe = None
         hit = jnp.zeros((r,), jnp.bool_)
     # --- stage the misses: compact them into the [S] id buffer ----------
-    miss = jnp.logical_and(req_valid, ~hit)
-    cs = jnp.cumsum(miss.astype(jnp.int32))
-    staged = jnp.logical_and(miss, cs <= s)
-    slot_u = cs - 1                       # staging slot per unique slot
-    miss_ids = jnp.full((s,), -1, jnp.int32)
-    miss_ids = miss_ids.at[jnp.where(staged, slot_u, s)].set(
-        req_ids, mode="drop")
-    n_staged = jnp.sum(staged).astype(jnp.int32)
-    n_overflow = jnp.sum(miss).astype(jnp.int32) - n_staged
-    if tier is not None:
-        out_u = jnp.where(hit[:, None], probe.rows, 0)
-    else:
-        out_u = jnp.zeros((r, d), dtype)
-    served_u = jnp.logical_or(hit, staged)
-    out = out_u[inverse]
-    dropped = jnp.sum(~served_u[inverse]).astype(jnp.int32)
-    req = HostMissRequest(ids=miss_ids,
-                          slot=slot_u[inverse].astype(jnp.int32),
-                          patch=staged[inverse])
+    with _stage("owner_fetch"):
+        miss = jnp.logical_and(req_valid, ~hit)
+        cs = jnp.cumsum(miss.astype(jnp.int32))
+        staged = jnp.logical_and(miss, cs <= s)
+        slot_u = cs - 1                   # staging slot per unique slot
+        miss_ids = jnp.full((s,), -1, jnp.int32)
+        miss_ids = miss_ids.at[jnp.where(staged, slot_u, s)].set(
+            req_ids, mode="drop")
+        n_staged = jnp.sum(staged).astype(jnp.int32)
+        n_overflow = jnp.sum(miss).astype(jnp.int32) - n_staged
+    with _stage("slot_scatter"):
+        if tier is not None:
+            out_u = jnp.where(hit[:, None], probe.rows, 0)
+        else:
+            out_u = jnp.zeros((r, d), dtype)
+        served_u = jnp.logical_or(hit, staged)
+        out = out_u[inverse]
+        dropped = jnp.sum(~served_u[inverse]).astype(jnp.int32)
+        req = HostMissRequest(ids=miss_ids,
+                              slot=slot_u[inverse].astype(jnp.int32),
+                              patch=staged[inverse])
     gather_bytes = s * (4 + d * jnp.dtype(dtype).itemsize)
     stats = FetchStats(
         jnp.int32(r), n_staged, dropped,
@@ -747,21 +789,23 @@ def _host_fetch(ids, axis_name, capacity_slack, capacity, cache, cache_cfg,
     n_ins = n_adm
     if cache_cfg.mode == "tiered":
         l2_hit = probe.ctx[2]
-        new_l1, n_l1_ins = cache_insert(cache.l1, req_ids, probe.rows,
-                                        l2_hit, cache_cfg.l1_config())
+        with _stage("cache_insert"):
+            new_l1, n_l1_ins = cache_insert(cache.l1, req_ids, probe.rows,
+                                            l2_hit, cache_cfg.l1_config())
         new_cache = TieredCache(l1=new_l1, l2=cache.l2)
         n_ins = n_ins + n_l1_ins
-    n_hits = jnp.sum(probe.hit).astype(jnp.int32)
-    n_l1 = jnp.sum(probe.l1_hit).astype(jnp.int32)
-    n_local = jnp.sum(probe.local).astype(jnp.int32)
     row_bytes = d * jnp.dtype(dtype).itemsize
-    cstats = CacheStats(
-        n_hits=n_hits, n_misses=n_overflow, n_inserted=n_ins,
-        bytes_saved=(n_l1 + n_local) * row_bytes, n_local_hits=n_local,
-        n_shard_hits=n_hits - n_l1 - n_local, n_l1_hits=n_l1,
-        n_probe_demoted=probe.wire.n_demoted,
-        probe_hit_peak=probe.wire.hit_peak,
-        n_l3_hits=n_staged)
+    with _stage("cache_probe"):
+        n_hits = jnp.sum(probe.hit).astype(jnp.int32)
+        n_l1 = jnp.sum(probe.l1_hit).astype(jnp.int32)
+        n_local = jnp.sum(probe.local).astype(jnp.int32)
+        cstats = CacheStats(
+            n_hits=n_hits, n_misses=n_overflow, n_inserted=n_ins,
+            bytes_saved=(n_l1 + n_local) * row_bytes, n_local_hits=n_local,
+            n_shard_hits=n_hits - n_l1 - n_local, n_l1_hits=n_l1,
+            n_probe_demoted=probe.wire.n_demoted,
+            probe_hit_peak=probe.wire.hit_peak,
+            n_l3_hits=n_staged)
     return out, new_cache, stats, cstats, req
 
 
@@ -923,7 +967,8 @@ def fetch_rows(
         return _host_fetch(ids, axis_name, capacity_slack, capacity,
                            cache, cache_cfg, host_admit, d, dtype, w)
     if w == 1 and cache is None:
-        out = table_local[jnp.clip(ids, 0, rows - 1)]
+        with _stage("owner_fetch"):
+            out = table_local[jnp.clip(ids, 0, rows - 1)]
         if return_stats:
             if dedup:
                 n_unique = dedup_requests(ids)[3].astype(jnp.int32)
@@ -953,53 +998,57 @@ def fetch_rows(
     tier = None
     if cache is not None:
         tier = _cache_tier(cache_cfg)
+    probe = None
     if tier is not None:
         probe = tier.probe(cache, cache_cfg, req_ids, req_valid,
                            axis_name, slack_cap, w)
-        route_valid = jnp.logical_and(req_valid, ~probe.hit)
-    else:
-        probe = None
-        route_valid = req_valid
     # --- route the (remaining) requests to their owners ------------------
-    if w == 1:
-        fetched = table_local[jnp.clip(req_ids, 0, rows - 1)]
-        fetched = jnp.where(route_valid[:, None], fetched, 0)
-        served_r = route_valid
-    else:
-        fetched, served_r = _routed_fetch(
-            table_local, req_ids, route_valid, axis_name, cap, w, rows)
-    n_routed = jnp.sum(route_valid).astype(jnp.int32)
+    with _stage("owner_fetch"):
+        route_valid = (req_valid if probe is None
+                       else jnp.logical_and(req_valid, ~probe.hit))
+        if w == 1:
+            fetched = table_local[jnp.clip(req_ids, 0, rows - 1)]
+            fetched = jnp.where(route_valid[:, None], fetched, 0)
+            served_r = route_valid
+        else:
+            fetched, served_r = _routed_fetch(
+                table_local, req_ids, route_valid, axis_name, cap, w, rows)
+        n_routed = jnp.sum(route_valid).astype(jnp.int32)
     # --- merge hits back, offer served misses for admission --------------
     new_cache = None
     cstats = None
     if tier is not None:
-        out_u = jnp.where(probe.hit[:, None], probe.rows, fetched)
-        served_u = jnp.logical_or(probe.hit, served_r)
-        should = jnp.logical_and(route_valid, served_r)
+        with _stage("slot_scatter"):
+            out_u = jnp.where(probe.hit[:, None], probe.rows, fetched)
+            served_u = jnp.logical_or(probe.hit, served_r)
+            should = jnp.logical_and(route_valid, served_r)
         new_cache, n_ins = tier.admit(cache, cache_cfg, probe, req_ids,
                                       fetched, should, axis_name, w)
-        n_hits = jnp.sum(probe.hit).astype(jnp.int32)
-        n_l1 = jnp.sum(probe.l1_hit).astype(jnp.int32)
-        n_local = jnp.sum(probe.local).astype(jnp.int32)
         row_bytes = table_local.shape[1] * jnp.dtype(table_local.dtype).itemsize
-        cstats = CacheStats(
-            n_hits=n_hits, n_misses=n_routed, n_inserted=n_ins,
-            bytes_saved=(n_l1 + n_local) * row_bytes, n_local_hits=n_local,
-            n_shard_hits=n_hits - n_l1 - n_local, n_l1_hits=n_l1,
-            n_probe_demoted=probe.wire.n_demoted,
-            probe_hit_peak=probe.wire.hit_peak,
-            n_l3_hits=jnp.int32(0))
+        with _stage("cache_probe"):
+            n_hits = jnp.sum(probe.hit).astype(jnp.int32)
+            n_l1 = jnp.sum(probe.l1_hit).astype(jnp.int32)
+            n_local = jnp.sum(probe.local).astype(jnp.int32)
+            cstats = CacheStats(
+                n_hits=n_hits, n_misses=n_routed, n_inserted=n_ins,
+                bytes_saved=(n_l1 + n_local) * row_bytes,
+                n_local_hits=n_local,
+                n_shard_hits=n_hits - n_l1 - n_local, n_l1_hits=n_l1,
+                n_probe_demoted=probe.wire.n_demoted,
+                probe_hit_peak=probe.wire.hit_peak,
+                n_l3_hits=jnp.int32(0))
         n_unique = n_routed          # ids that went to their owner
     else:
         out_u, served_u = fetched, served_r
-    if dedup:
-        out = out_u[inverse]
-        # a dropped unique id zero-fills EVERY duplicate slot it backed —
-        # count affected request slots, not wire slots
-        dropped = jnp.sum(~served_u[inverse])
-    else:
-        out = out_u
-        dropped = jnp.sum(~served_u)
+    with _stage("slot_scatter"):
+        if dedup:
+            out = out_u[inverse]
+            # a dropped unique id zero-fills EVERY duplicate slot it
+            # backed — count affected request slots, not wire slots
+            dropped = jnp.sum(~served_u[inverse])
+        else:
+            out = out_u
+            dropped = jnp.sum(~served_u)
     stats = FetchStats(jnp.int32(r), jnp.int32(n_unique),
                        dropped.astype(jnp.int32),
                        jnp.int32(probe.wire.probe_bytes if tier is not None
@@ -1065,40 +1114,49 @@ def _worker_generate(
     n_distinct`` holds for every traced configuration.
     """
     b = seeds.shape[0]
-    me = lax.axis_index(axis_name)
-    rng = jax.random.fold_in(rng, me)
-    hop_rngs = jax.random.split(rng, max(len(fanouts), 2))
+    with _stage("edge_scan"):
+        me = lax.axis_index(axis_name)
+        rng = jax.random.fold_in(rng, me)
+        hop_rngs = jax.random.split(rng, max(len(fanouts), 2))
 
-    frontier = lax.all_gather(seeds, axis_name, tiled=True)   # [B] global
-    parent_mask = jnp.ones(frontier.shape, jnp.bool_)
+    with _stage("frontier"):
+        frontier = lax.all_gather(seeds, axis_name, tiled=True)  # [B]
+        parent_mask = jnp.ones(frontier.shape, jnp.bool_)
     hops, masks = [], []
     shape = (b,)                # local tree shape accumulator
     local_rows = b              # b * k_1 * ... * k_l (this worker's rows)
     for level, k in enumerate(fanouts):
-        cand = local_candidates(indptr, indices, frontier, k, hop_rngs[level])
-        # padding must not spawn children:
-        cand = Candidates(
-            ids=cand.ids,
-            keys=jnp.where(parent_mask[:, None], cand.keys, jnp.inf),
-        )
+        with _stage("edge_scan"):
+            cand = local_candidates(indptr, indices, frontier, k,
+                                    hop_rngs[level])
+            # padding must not spawn children:
+            cand = Candidates(
+                ids=cand.ids,
+                keys=jnp.where(parent_mask[:, None], cand.keys, jnp.inf),
+            )
         if merge_mode == "reduce_scatter":
             # beyond-paper: recursive-halving merge — each worker
             # materializes only ITS segment of the frontier
             # (tree_reduce.py); ~4x less ICI traffic than the butterfly
             # at W=16.
-            seg = tree_reduce_scatter(cand, merge_topk, axis_name)
-            m = jnp.isfinite(seg.keys)                        # [rows_l, k]
-            h = jnp.where(m, seg.ids, 0)
+            with _stage("tree_merge"):
+                seg = tree_reduce_scatter(cand, merge_topk, axis_name)
+                m = jnp.isfinite(seg.keys)                    # [rows_l, k]
+                h = jnp.where(m, seg.ids, 0)
             # the next frontier must still be GLOBAL (edge-centric: every
             # worker scans its local edges against all hop-l nodes)
-            h_all = lax.all_gather(h, axis_name, tiled=True)
-            m_all = lax.all_gather(m, axis_name, tiled=True)
+            with _stage("frontier"):
+                h_all = lax.all_gather(h, axis_name, tiled=True)
+                m_all = lax.all_gather(m, axis_name, tiled=True)
         else:
-            merged = tree_allreduce(cand, merge_topk, axis_name)  # [F, k]
-            m_all = jnp.isfinite(merged.keys)
-            h_all = jnp.where(m_all, merged.ids, 0)
-            h = lax.dynamic_slice_in_dim(h_all, me * local_rows, local_rows, 0)
-            m = lax.dynamic_slice_in_dim(m_all, me * local_rows, local_rows, 0)
+            with _stage("tree_merge"):
+                merged = tree_allreduce(cand, merge_topk, axis_name)
+                m_all = jnp.isfinite(merged.keys)             # [F, k]
+                h_all = jnp.where(m_all, merged.ids, 0)
+                h = lax.dynamic_slice_in_dim(h_all, me * local_rows,
+                                             local_rows, 0)
+                m = lax.dynamic_slice_in_dim(m_all, me * local_rows,
+                                             local_rows, 0)
         shape = shape + (k,)
         hops.append(h.reshape(shape))
         masks.append(m.reshape(shape))
@@ -1108,12 +1166,15 @@ def _worker_generate(
 
     # chain masks explicitly (the +inf-key propagation already implies this;
     # keep the invariant structural, not sampler-dependent)
-    for level in range(1, len(masks)):
-        masks[level] = jnp.logical_and(masks[level], masks[level - 1][..., None])
+    with _stage("tree_merge"):
+        for level in range(1, len(masks)):
+            masks[level] = jnp.logical_and(masks[level],
+                                           masks[level - 1][..., None])
 
     # --- feature shuffle: one deduplicated fetch for every node slot,
     # cache-probed first when a hot-node cache is threaded through ---
-    need = jnp.concatenate([seeds] + [h.reshape(-1) for h in hops])
+    with _stage("dedup"):
+        need = jnp.concatenate([seeds] + [h.reshape(-1) for h in hops])
     host = feature_store == "host"
     req = None
     if cache is not None and host:
@@ -1144,22 +1205,26 @@ def _worker_generate(
         n_hits, n_misses = jnp.int32(0), fstats.n_unique
         n_demoted = jnp.int32(0)
     d = x_local.shape[1] if x_local is not None else feat_dim
-    x_seed = feats[:b]
-    x_hops = []
-    off = b
-    n = b
-    for level, k in enumerate(fanouts):
-        n *= k
-        x = feats[off:off + n].reshape(masks[level].shape + (d,))
-        x_hops.append(x * masks[level][..., None])
-        off += n
+    with _stage("slot_scatter"):
+        x_seed = feats[:b]
+        x_hops = []
+        off = b
+        n = b
+        for level, k in enumerate(fanouts):
+            n *= k
+            x = feats[off:off + n].reshape(masks[level].shape + (d,))
+            x_hops.append(x * masks[level][..., None])
+            off += n
     # balance-table seeds are already distinct per worker — skip the dedup
     # front end for the label fetch
-    ys, ystats = fetch_rows(y_local, seeds, axis_name,
-                            capacity_slack=capacity_slack, dedup=False,
-                            return_stats=True)
-    labels = ys[:, 0].astype(jnp.int32)
+    with _stage("labels"):
+        ys, ystats = fetch_rows(y_local, seeds, axis_name,
+                                capacity_slack=capacity_slack, dedup=False,
+                                return_stats=True)
+        labels = ys[:, 0].astype(jnp.int32)
 
+    with _stage("slot_scatter"):
+        n_dropped = (fstats.n_dropped + ystats.n_dropped)[None]
     batch = SubgraphBatch(
         seeds=seeds,
         hops=tuple(hops),
@@ -1167,7 +1232,7 @@ def _worker_generate(
         x_seed=x_seed,
         x_hops=tuple(x_hops),
         labels=labels,
-        n_dropped=(fstats.n_dropped + ystats.n_dropped)[None],
+        n_dropped=n_dropped,
         n_cache_hits=n_hits[None],
         n_cache_misses=n_misses[None],
         n_probe_demoted=n_demoted[None],

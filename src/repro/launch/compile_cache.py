@@ -23,7 +23,12 @@ def enable_compile_cache() -> str:
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
     this sets no directory of its own; otherwise the cache goes to
-    ``<repo>/.jax_cache``."""
+    ``<repo>/.jax_cache``.  The key includes the program's metadata (its
+    name stacks and source lines): JAX leaves it out by default, and then
+    a program that differs only in its named scopes loads the entry an
+    older one wrote, whose operations a profiler trace names by the older
+    scopes."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -19,6 +19,17 @@ _PROBE = textwrap.dedent("""
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
         jax.jit(lambda x: x * 3 + 1)(jnp.arange(7.0)).block_until_ready()
+    if "--scope" in sys.argv:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        scope = sys.argv[sys.argv.index("--scope") + 1]
+
+        def f(x):
+            with jax.named_scope(scope):
+                return jnp.sort(x * 3 + 1)
+        text = jax.jit(f).lower(jnp.arange(7.0)).compile().as_text()
+        print("SCOPES", " ".join(sorted(
+            {n for n in ("first", "second") if f"/{n}/" in text})))
 """)
 
 
@@ -44,3 +55,12 @@ def test_cache_goes_to_the_variable_when_set(tmp_path):
 def test_cache_defaults_to_the_repo_checkout():
     want = str(REPO / ".jax_cache")
     assert _probe(None) == {"DIR": want, "CFG": want}
+
+
+def test_cached_program_keeps_its_own_name_stack(tmp_path):
+    """Two programs that differ only in a named scope do not share an
+    entry: the second compiles its own, and its operations carry its own
+    scope (a trace of it names them by the code that ran)."""
+    where = tmp_path / "jax-cache"
+    assert _probe(where, "--scope", "first")["SCOPES"] == "first"
+    assert _probe(where, "--scope", "second")["SCOPES"] == "second"

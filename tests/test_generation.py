@@ -1,11 +1,20 @@
 """Edge-centric generation primitives (single-worker units; the multi-worker
-integration runs in test_distributed.py subprocesses)."""
+integration runs in test_distributed.py subprocesses), and the stage
+scopes the generator's compiled program and profiler traces carry."""
+import json
+import os
+import subprocess
+import sys
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+import _stage_scopes
+from repro.core import generation
 from repro.core.baselines import (edge_centric_sample, node_centric_sample,
                                   sql_like_sample)
 from repro.core.generation import (Candidates, dedup_requests, fetch_rows,
@@ -238,3 +247,113 @@ def test_baselines_agree_on_sampled_set_validity(graph):
             assert got.issubset(adj[i]), (name, i, got, adj[i])
             if adj[i]:
                 assert mask[i].any(), (name, i)
+
+
+# --- stage scopes ----------------------------------------------------------
+# Each generation stage is a named scope (``generation.STAGES``); these
+# tests read the scopes back from the compiled program and from a profiler
+# trace, so a dropped or misplaced scope shows without a chip.
+
+_TESTS = Path(__file__).resolve().parent
+#: cases compiled on four virtual CPU devices: the cells' tiers, no cache,
+#: the host store, and the reduce-scatter merge's frontier all_gathers
+W4_CASES = ("uncached", "sharded", "tiered", "host-tiered", "reduce-scatter")
+
+
+def _check_report(rep, case, workers):
+    missing = _stage_scopes.expected_stages(case, workers) - set(rep["stages"])
+    assert not missing, f"{case} W={workers}: no op in stages {missing}"
+    assert not rep["unstaged"], \
+        f"{case} W={workers}: timed ops in no stage: {rep['unstaged']}"
+    assert rep["same_unscoped"], \
+        f"{case} W={workers}: the scopes changed the compiled instructions"
+
+
+@pytest.mark.parametrize("case", sorted(_stage_scopes.CASES))
+def test_stage_scopes_cover_the_generator(case):
+    """Every stage with work in the compiled generator carries its scope,
+    every timed instruction under ``jit(gen_fn)`` sits in a stage, and
+    the scopes are metadata only."""
+    _check_report(_stage_scopes.report(case), case, 1)
+
+
+@pytest.fixture(scope="module")
+def four_worker_reports():
+    env = dict(os.environ, XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               JAX_PLATFORMS="cpu")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [str(_TESTS.parent / "src"), env.get("PYTHONPATH", "")])
+    proc = subprocess.run(
+        [sys.executable, str(_TESTS / "_stage_scopes.py"), *W4_CASES],
+        capture_output=True, text=True, timeout=600, env=env, cwd=_TESTS)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("case", W4_CASES)
+def test_stage_scopes_cover_the_generator_on_four_workers(
+        four_worker_reports, case):
+    """The same on four workers, where the frontier all_gather, the
+    butterfly's collective-permutes and the all_to_all rounds run."""
+    _check_report(four_worker_reports[case], case, 4)
+
+
+def test_stage_of_takes_the_outermost_stage():
+    f = _stage_scopes.stage_of
+    assert f("jit(step)/jit(gen_fn)/labels/owner_fetch/gather") == "labels"
+    assert f("jit(gen_fn)/cache_insert/jit(searchsorted)/vmap()/while") \
+        == "cache_insert"
+    assert f("jit(gen_fn)/vmap(dedup)/sort") == "dedup"
+    assert f("jit(step)/jit(gen_fn)/concatenate") is None
+    with pytest.raises(ValueError, match="unknown generation stage"):
+        generation._stage("no_such_stage")
+
+
+def test_stage_times_partition_the_generator_trace(graph, tmp_path):
+    """In a profiler trace of the cached generator, the device time of the
+    ops under ``jit(gen_fn)`` grouped by outermost stage sums to the
+    generator's time: the stages partition it."""
+    from repro.core.feature_cache import CacheConfig
+    from repro.core.partition import partition_edges
+    from repro.graph.synthetic import node_features, node_labels
+    from repro.launch.mesh import make_mesh
+    sys.path.insert(0, str(_TESTS.parent))
+    from chipbench import trace
+
+    mesh = make_mesh((1,), ("data",))
+    n = graph.n_nodes
+    cfg = CacheConfig(n_rows=64, admit=1, assoc=4, mode="sharded")
+    gen, dev, cache = generation.make_distributed_generator(
+        mesh, partition_edges(graph, 1), node_features(n, 16),
+        node_labels(n, 8), fanouts=(8, 4), cache_cfg=cfg)
+    seeds = jnp.arange(64, dtype=jnp.int32)[None]
+    names = trace.hlo_op_names(
+        gen.lower(dev, seeds, jax.random.PRNGKey(0), cache)
+        .compile().as_text())
+    for t in range(2):
+        cache = gen(dev, seeds, jax.random.PRNGKey(t), cache)[1]
+    jax.block_until_ready(cache)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("window"):
+            for t in range(3):
+                cache = gen(dev, seeds + t, jax.random.PRNGKey(t), cache)[1]
+            jax.block_until_ready(cache)
+    finally:
+        jax.profiler.stop_trace()
+    ops, spans = trace.load(trace.find_xplane(str(tmp_path)),
+                            {"jit_gen_fn": names})
+
+    def stage(op):
+        return _stage_scopes.stage_of(op.scope) \
+            if "jit(gen_fn)" in op.scope else "none"
+    measured = ("edge_scan", "dedup", "cache_probe", "owner_fetch",
+                "cache_insert", "slot_scatter")
+    groups = {s: (lambda op, s=s: stage(op) == s) for s in measured}
+    groups["gen"] = lambda op: "jit(gen_fn)" in op.scope
+    groups["other"] = lambda op: stage(op) not in measured + ("none",)
+    g = trace.summarize(ops, spans, "window", groups).groups_s
+    assert g["gen"] > 0
+    assert g["cache_insert"] > 0 and g["dedup"] > 0 and g["owner_fetch"] > 0
+    parts = sum(g[s] for s in measured) + g["other"]
+    assert abs(parts - g["gen"]) <= 0.05 * g["gen"], (parts, g)

@@ -114,6 +114,19 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     assert "tpu_custom_call" in hlo, f"{name}: no Mosaic kernel in the HLO"
 
 
+@pytest.mark.parametrize("case", ["sharded", "tiered"])
+def test_stage_scopes_are_metadata_on_v5e(case, topo, no_compile_cache):
+    """The generator of each W=1 cell's tier, compiled by the chip's
+    compiler: every timed op under ``jit(gen_fn)`` sits in a stage, and
+    without the scopes the program is the same instruction for
+    instruction."""
+    import _stage_scopes
+    rep = _stage_scopes.report(case, devices=topo.devices)
+    assert not rep["unstaged"], rep["unstaged"]
+    assert rep["same_unscoped"]
+    assert _stage_scopes.expected_stages(case, 1) <= set(rep["stages"])
+
+
 def _with_sharding(tree, sharding):
     return jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
