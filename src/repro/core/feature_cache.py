@@ -665,47 +665,58 @@ def cache_insert(
     the counter IS the victim policy, so contended candidates keep their
     progress.  Distinct ids colliding on one slot within a single batch
     are resolved to ONE winner (highest request index) *before* any
-    scatter: the state is four arrays updated by four scatters, and
-    duplicate scatter indices apply in unspecified order per scatter —
-    without a pre-resolved winner, ``keys[s]`` could take id A while
-    ``rows[s]`` takes B's row and every later probe of A would silently
-    return B's features.
+    write: without a pre-resolved winner, ``keys[s]`` could take id A
+    while ``rows[s]`` takes B's row and every later probe of A would
+    silently return B's features.
+
+    Offers are resolved per slot: one sort orders them by (set, id); a
+    segmented scan over that order ranks each distinct new candidate
+    within its set, and the rank picks its way from a per-set
+    [n_sets, assoc] victim-preference table; one ``max`` scatter into [C]
+    names each slot's winner (the highest request index that chose it);
+    every state array is then rewritten by C-sized selects from what the
+    winner offered, so nothing of R rows is written.
     """
     if cfg.n_rows != cache.n_rows:
         raise ValueError(f"cfg.n_rows {cfg.n_rows} != cache state rows "
                          f"{cache.n_rows}: inserting under a mismatched "
                          f"layout silently corrupts the placement")
-    a, admit = cfg.assoc, cfg.admit
+    a, admit, n_sets = cfg.assoc, cfg.admit, cfg.n_sets
     c = cache.n_rows
     r = ids.shape[0]
     if r == 0:
-        # empty offer batch: the rank machinery below concatenates a
-        # length-1 group-start marker, which has no length-0 analogue
+        # empty offer batch: the group-start markers below concatenate a
+        # length-1 first marker, which has no length-0 analogue
         return cache, jnp.int32(0)
-    sets = hash_slots(ids, cfg.n_sets)
-    slots = sets[:, None] * a + jnp.arange(a, dtype=jnp.int32)[None, :]
-    keys_w = cache.keys[slots]                              # [R, A]
-    tags_w = cache.tags[slots]
-    counts_w = cache.counts[slots]
-    tag_match = tags_w == ids[:, None]
-    has_tag = tag_match.any(axis=-1)
+    # masked offers sort into a sentinel set past the last real one.  The
+    # offers of one (set, id) group share everything below but their
+    # request index, and only the largest of those counts, so their order
+    # within the group is free: an unstable two-key sort suffices
+    sets_eff = jnp.where(should, hash_slots(ids, n_sets), n_sets)
+    s_sorted, i_sorted, idx_sorted = jax.lax.sort(
+        (sets_eff, ids, jnp.arange(r, dtype=jnp.int32)), num_keys=2,
+        is_stable=False)
+    valid = s_sorted < n_sets
+    s_row = jnp.minimum(s_sorted, n_sets - 1)
+    tag_match = cache.tags.reshape(n_sets, a)[s_row] == i_sorted[:, None]
+    has_tag = jnp.logical_and(valid, tag_match.any(axis=-1))
     tag_way = jnp.argmax(tag_match, axis=-1).astype(jnp.int32)
-    # victim policy: VIRGIN ways first (no resident AND no candidate in
-    # flight — a way whose tag is mid-admission carries progress worth as
-    # much as a resident's, so it scores by its counter like occupied
-    # ways do), then smallest counter.  Ways claimed by a same-batch
-    # TAGGED offer are excluded outright (huge score): the tagged offer
-    # sits outside the preference order on its tag way, and a new
-    # candidate routed onto it would trample its admission progress while
-    # virgin ways sit free.
-    claim_slot = sets * a + tag_way
-    claimed = jnp.zeros((c,), jnp.bool_).at[
-        jnp.where(jnp.logical_and(should, has_tag), claim_slot, c)
-    ].set(True, mode="drop")
-    victim_score = jnp.where(jnp.logical_and(keys_w < 0, tags_w < 0),
-                             -1, counts_w)
-    victim_score = jnp.where(claimed[slots], jnp.int32(2**30), victim_score)
-    ways_pref = jnp.argsort(victim_score, axis=-1).astype(jnp.int32)  # [R, A]
+    # victim policy, per set: VIRGIN ways first (no resident AND no
+    # candidate in flight — a way whose tag is mid-admission scores by its
+    # counter like occupied ways do), then smallest counter.  Ways claimed
+    # by a same-batch TAGGED offer are excluded (huge score): a new
+    # candidate routed onto the tag way would trample its admission
+    # progress while virgin ways sit free.  ``claimed`` is an int32 ``max``
+    # scatter: the TPU compiler sorts the R indices of a boolean ``set``
+    # scatter before it applies one.
+    claimed = jnp.zeros((c,), jnp.int32).at[
+        jnp.where(has_tag, s_sorted * a + tag_way, c)
+    ].max(1, mode="drop") > 0
+    victim_score = jnp.where(
+        jnp.logical_and(cache.keys < 0, cache.tags < 0), -1, cache.counts)
+    victim_score = jnp.where(claimed, jnp.int32(2**30), victim_score)
+    ways_pref = jnp.argsort(victim_score.reshape(n_sets, a),
+                            axis=-1).astype(jnp.int32).reshape(c)
     # Same-set offers within ONE batch must not all pick the same victim
     # way (the per-slot winner resolution below would then drop all but
     # one even with free ways left) — rank each NEW candidate within its
@@ -715,44 +726,37 @@ def cache_insert(
     # admission round) must share a way so the per-slot winner keeps
     # exactly one copy, and tagged offers consume no preference slot
     # (they keep their tag way).
-    sets_eff = jnp.where(should, sets, cfg.n_sets)
-    o1 = jnp.argsort(ids)
-    order = o1[jnp.argsort(sets_eff[o1])]    # stable: (set, id) lexicographic
-    s_sorted = sets_eff[order]
-    i_sorted = ids[order]
-    new_group = jnp.concatenate([
-        jnp.ones((1,), jnp.bool_),
-        jnp.logical_or(s_sorted[1:] != s_sorted[:-1],
-                       i_sorted[1:] != i_sorted[:-1])])
+    first = jnp.ones((1,), jnp.bool_)
+    new_set = jnp.concatenate([first, s_sorted[1:] != s_sorted[:-1]])
+    new_group = jnp.logical_or(
+        new_set, jnp.concatenate([first, i_sorted[1:] != i_sorted[:-1]]))
     # cumulative count of NEW-CANDIDATE group starts: constant across a
-    # group (increments only at group starts), so duplicates share a rank
-    nontag_start = jnp.logical_and(new_group, ~has_tag[order])
-    ng = jnp.cumsum(nontag_start).astype(jnp.int32)
-    set_start = jnp.searchsorted(s_sorted, s_sorted, side="left")
-    before_set = ng[set_start] - nontag_start[set_start].astype(jnp.int32)
-    rank = jnp.zeros((r,), jnp.int32).at[order].set(ng - before_set - 1)
-    victim_way = jnp.take_along_axis(ways_pref, (rank % a)[:, None],
-                                     axis=-1)[:, 0]
-    way = jnp.where(has_tag, tag_way, victim_way)
-    slot = sets * a + way                                   # [R]
-    prev = jnp.take_along_axis(counts_w, way[:, None], axis=-1)[:, 0]
-    new_count = jnp.where(has_tag, prev + 1, 1)
+    # group (increments only at group starts), so duplicates share a rank;
+    # ``ng`` never falls, so a running max carries each set's count
+    # before its first offer down the set
+    nontag_start = jnp.logical_and(new_group, ~has_tag)
+    ng = jnp.cumsum(nontag_start, dtype=jnp.int32)
+    before_set = jax.lax.cummax(
+        jnp.where(new_set, ng - nontag_start.astype(jnp.int32), 0))
+    rank = ng - before_set - 1
+    victim_way = ways_pref[s_row * a + rank % a]
+    slot = s_row * a + jnp.where(has_tag, tag_way, victim_way)
     # one deterministic winner per slot among the offers (max-combiner
     # scatter is order-independent); only the winner touches the slot
-    idx = jnp.arange(r, dtype=jnp.int32)
     win = jnp.full((c,), -1, jnp.int32).at[
-        jnp.where(should, slot, c)].max(idx, mode="drop")
-    offer = jnp.logical_and(should, win[slot] == idx)
+        jnp.where(valid, slot, c)].max(idx_sorted, mode="drop")
+    offer = win >= 0
+    w = jnp.maximum(win, 0)
+    ids_w = ids[w]
+    # the winner carries a tag exactly when its tag sits in the slot it won
+    new_count = jnp.where(cache.tags == ids_w, cache.counts + 1, 1)
     install = jnp.logical_and(offer, new_count >= admit)
-    # not-selected offers scatter OUT OF BOUNDS so mode="drop" discards them
-    s_track = jnp.where(offer, slot, c)
-    s_install = jnp.where(install, slot, c)
     new = FeatureCache(
-        keys=cache.keys.at[s_install].set(ids, mode="drop"),
-        rows=cache.rows.at[s_install].set(rows.astype(cache.rows.dtype),
-                                          mode="drop"),
-        tags=cache.tags.at[s_track].set(ids, mode="drop"),
-        counts=cache.counts.at[s_track].set(new_count, mode="drop"),
+        keys=jnp.where(install, ids_w, cache.keys),
+        rows=jnp.where(install[:, None], rows[w].astype(cache.rows.dtype),
+                       cache.rows),
+        tags=jnp.where(offer, ids_w, cache.tags),
+        counts=jnp.where(offer, new_count, cache.counts),
     )
     return new, jnp.sum(install).astype(jnp.int32)
 

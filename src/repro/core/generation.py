@@ -1387,14 +1387,20 @@ def make_generator_fn(
             return batch, _stats_tail(stats)
         return out
 
+    # the updated cache state's way out belongs to the insert: the TPU
+    # compiler fuses its relayout with the insert's final selects
+    def restore_state(cache):
+        with _stage("cache_insert"):
+            return restore_worker_axis(cache)
+
     def worker_fn_cached(indptr, indices, xs, ys, seeds, rng, cache):
         out = worker_gen(indptr[0], indices[0], xs, ys, seeds[0],
                          rng, squeeze_worker_axis(cache))
         if collect_stats:
             batch, cache, stats = out
-            return batch, restore_worker_axis(cache), _stats_tail(stats)
+            return batch, restore_state(cache), _stats_tail(stats)
         batch, cache = out
-        return batch, restore_worker_axis(cache)
+        return batch, restore_state(cache)
 
     # forward-only serve form: the frozen admit stage already returns the
     # state untouched, so there is no next cache version to ship out —
@@ -1424,11 +1430,11 @@ def make_generator_fn(
             host_admit=(adm_ids[0], adm_rows[0]))
         if collect_stats:
             batch, cache, req, stats = out
-            return (batch, restore_worker_axis(cache),
+            return (batch, restore_state(cache),
                     jax.tree.map(lambda a: a[None], req),
                     _stats_tail(stats))
         batch, cache, req = out
-        return (batch, restore_worker_axis(cache),
+        return (batch, restore_state(cache),
                 jax.tree.map(lambda a: a[None], req))
 
     if host and cached:

@@ -3,6 +3,8 @@ set-associative), the cache-aware fetch front end (bit-identical to the
 uncached path), and the Zipf wire-slot reduction the subsystem exists
 for.  The sharded-mode multiworker path runs in test_distributed.py
 subprocesses (forced device counts)."""
+import json
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -289,6 +291,115 @@ def test_duplicate_id_offers_occupy_one_way():
                               jnp.ones(4, bool), cfg2)
     assert int(n3) == 0
     assert int(np.asarray(cache3.tags == 77).sum()) == 1
+
+
+# ------------------------------------ exactness against the former insert
+
+EXACT_C = 64
+
+
+def _full_state(cfg, d, rng):
+    """A cache whose every slot holds a resident and a candidate, each an
+    id of the slot's own set, with counts short of admission or past it."""
+    c, a = cfg.n_rows, cfg.assoc
+    pool = np.arange(64 * c, dtype=np.int32)
+    sets = np.asarray(hash_slots(jnp.asarray(pool), cfg.n_sets))
+    keys, tags = np.empty(c, np.int32), np.empty(c, np.int32)
+    for s in range(cfg.n_sets):
+        mine = pool[sets == s]
+        keys[s * a:(s + 1) * a] = mine[:a]
+        tags[s * a:(s + 1) * a] = mine[a:2 * a]
+    return FeatureCache(
+        keys=jnp.asarray(keys),
+        rows=jnp.asarray(rng.standard_normal((c, d)).astype(np.float32)),
+        tags=jnp.asarray(tags),
+        counts=jnp.asarray(rng.integers(0, cfg.admit + 2, c, np.int32)))
+
+
+def _offer_stream(kind, r, cfg, state, rng):
+    """``(ids, should)`` of one offer batch of length ``r``."""
+    c = cfg.n_rows
+    zipf = (rng.zipf(1.3, r) % (4 * c)).astype(np.int32)
+    if kind == "zipf":
+        return zipf, rng.random(r) < 0.8
+    if kind == "same_set":
+        # many distinct ids (and repeats) on two sets: more new
+        # candidates than ways
+        pool = np.arange(64 * c, dtype=np.int32)
+        sets = np.asarray(hash_slots(jnp.asarray(pool), cfg.n_sets))
+        mine = pool[sets < 2][:8 * cfg.assoc]
+        return rng.choice(mine, r).astype(np.int32), np.ones(r, bool)
+    if kind == "masked":
+        return zipf, np.zeros(r, bool)
+    # candidates in flight offered again, among new ids
+    tags = np.asarray(state.tags)
+    tags = tags[tags >= 0]
+    if tags.size:
+        zipf = np.where(rng.random(r) < 0.6, rng.choice(tags, r), zipf)
+    return zipf.astype(np.int32), rng.random(r) < 0.9
+
+
+@pytest.mark.parametrize("r", [0, 1, EXACT_C // 2, 8 * EXACT_C])
+@pytest.mark.parametrize("admit", [1, 2, 3])
+@pytest.mark.parametrize("assoc", [1, 2, 4])
+def test_insert_matches_former_insert_bit_for_bit(assoc, admit, r):
+    """The per-slot insert leaves the state the former per-offer insert
+    (``tests/_cache_insert_ref.py``) leaves, leaf for leaf and bit for
+    bit, with the same insert count, over multi-step sequences from an
+    empty and from a full cache: Zipf-duplicated ids, many ids of one
+    set, all offers masked, and candidates in flight offered again."""
+    from _cache_insert_ref import cache_insert as former_insert
+
+    cfg = CacheConfig(EXACT_C, admit=admit, assoc=assoc)
+    new_fn = jax.jit(lambda s, i, x, m: cache_insert(s, i, x, m, cfg))
+    old_fn = jax.jit(lambda s, i, x, m: former_insert(s, i, x, m, cfg))
+    rng = np.random.default_rng(100 * assoc + 10 * admit + r)
+    d = 3
+    for start in (init_cache(EXACT_C, d), _full_state(cfg, d, rng)):
+        new = old = start
+        for step, kind in enumerate(("zipf", "same_set", "masked",
+                                     "tagged", "zipf", "tagged")):
+            ids, should = _offer_stream(kind, r, cfg, new, rng)
+            rows = rng.standard_normal((r, d)).astype(np.float32)
+            new, n_new = new_fn(new, ids, rows, should)
+            old, n_old = old_fn(old, ids, rows, should)
+            for name, x, y in zip(FeatureCache._fields, new, old):
+                x, y = np.asarray(x), np.asarray(y)
+                assert x.dtype == y.dtype and x.tobytes() == y.tobytes(), \
+                    (kind, step, name)
+            assert int(n_new) == int(n_old), (kind, step)
+
+
+@pytest.mark.parametrize("mode", ["tiered", "sharded"])
+def test_fetch_states_match_former_insert(mode):
+    """Under ``fetch_rows`` at W=1 (the tiered tier's L2 admission and L1
+    promotion), the caches evolve as they did with the former insert."""
+    import _cache_insert_ref
+
+    assert _cache_insert_ref.same_history(
+        _cache_insert_ref.fetch_states(mode, 1, former=False),
+        _cache_insert_ref.fetch_states(mode, 1, former=True))
+
+
+def test_fetch_states_match_former_insert_on_four_workers():
+    """The same for the sharded tier on four virtual CPU devices, where
+    shard holders admit W x cap offers with the same id from several
+    workers."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    tests = Path(__file__).resolve().parent
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [str(tests.parent / "src"), env.get("PYTHONPATH", "")])
+    proc = subprocess.run(
+        [sys.executable, str(tests / "_cache_insert_ref.py"), "sharded", "4"],
+        capture_output=True, text=True, timeout=600, env=env, cwd=tests)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    assert json.loads(proc.stdout.strip().splitlines()[-1]) == {"same": True}
 
 
 # ------------------------------------------------------------- hash guards

@@ -11,6 +11,7 @@ their entries could not be read back without a chip.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +22,8 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 
 from repro.configs import get_config
 from repro.core.config import TrainConfig
-from repro.core.feature_cache import CacheConfig, cache_state_specs
+from repro.core.feature_cache import (CacheConfig, FeatureCache,
+                                      cache_insert, cache_state_specs)
 from repro.core.generation import make_generator_fn, probe_round_capacity
 from repro.core.pipeline import make_pipelined_step
 from repro.graph.subgraph import batch_specs, slots_per_seed
@@ -125,6 +127,33 @@ def test_stage_scopes_are_metadata_on_v5e(case, topo, no_compile_cache):
     assert not rep["unstaged"], rep["unstaged"]
     assert rep["same_unscoped"]
     assert _stage_scopes.expected_stages(case, 1) <= set(rep["stages"])
+
+
+def test_cache_insert_resolves_per_slot_on_v5e(one_chip):
+    """The insert at the 2-hop cell's shapes (861,184 offers into 4096
+    four-way slots of 128 floats), compiled by the chip's compiler, has
+    no ``while`` loop (a binary search over the offers) and no scatter
+    whose updates carry a row of D per offer (an R x D write)."""
+    r, c, d = 861_184, 4096, 128
+    cfg = CacheConfig(c, admit=2, assoc=4, mode="sharded")
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    state = FeatureCache(spec((c,), jnp.int32), spec((c, d), jnp.float32),
+                         spec((c,), jnp.int32), spec((c,), jnp.int32))
+    text = jax.jit(lambda s, i, x, m: cache_insert(s, i, x, m, cfg)).lower(
+        state, spec((r,), jnp.int32), spec((r, d), jnp.float32),
+        spec((r,), jnp.bool_)).compile().as_text()
+    assert not re.search(r"\swhile\(", text), "a while loop over the offers"
+    # instruction names are unique in the module: a scatter's updates
+    # operand is defined, with its shape, on a line of its own
+    shapes = dict(re.findall(r"%([\w.\-]+) = \w+\[([\d,]*)\]", text))
+    scatters = re.findall(r"\sscatter\(%[\w.\-]+, %[\w.\-]+, %([\w.\-]+)\)",
+                          text)
+    assert scatters, "no scatter found: the HLO text changed form"
+    for updates in scatters:
+        n = np.prod([int(k) for k in shapes[updates].split(",") if k])
+        assert n < r * d, f"scatter of {shapes[updates]} updates"
 
 
 def _with_sharding(tree, sharding):
