@@ -38,23 +38,24 @@ def readings(run) -> dict:
     from chipbench import checks, datasets, reference
     ds, train = run.s.dataset, run.s.cfg["train"]
     table, labels = datasets.device_tables(run.s.cfg["dataset"], ds.n_nodes)
-    ref = reference.run_steps(train, run.params0, table, labels,
-                              run.batches)
+
+    def steps(**variant):
+        return reference.run_steps(
+            train, run.params0, table, labels, run.batches,
+            forward=run.s.family.forward, block=run.s.family.BLOCK,
+            **variant)
+    ref = steps()
     out = {}
 
     def gaps(losses, grad1, p3):
         return checks.model_gaps(losses, grad1, run.params0, p3, *ref)
 
     out["program"] = gaps(run.losses, run.grad1, run.p3)
-    out["control"] = gaps(*reference.run_steps(
-        train, run.params0, table, labels, run.batches, dtype=jnp.bfloat16))
+    out["control"] = gaps(*steps(dtype=jnp.bfloat16))
     b = len(run.batches[0]["seeds"])
-    out["half_batch"] = gaps(*reference.run_steps(
-        train, run.params0, table, labels, run.batches, rows=b // 2))
+    out["half_batch"] = gaps(*steps(rows=b // 2))
     if run.s.workers > 1:
-        out["one_worker"] = gaps(*reference.run_steps(
-            train, run.params0, table, labels, run.batches,
-            rows=run.s.batch))
+        out["one_worker"] = gaps(*steps(rows=run.s.batch))
     exact = {"bad_ids": 0, "bad_masks": 0}
     for batch in run.batches:
         c = checks.check_sample(ds.indptr, ds.indices, batch["seeds"],

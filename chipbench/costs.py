@@ -1,11 +1,6 @@
-"""Operations and bytes the algorithm needs, from shapes alone.
-
-``model_flops_per_seed`` counts the dense transforms of the GCN's
-forward pass and the backward operations training needs: the weight
-gradients of every transform, and the input gradients of every transform
-whose input depends on a parameter (not those of the first convolution,
-whose inputs are features).  The masked means are reductions, not
-matrix products, and are not counted.
+"""Operations and bytes the algorithm needs, from shapes alone.  A model
+family's operations per seed are its own (``models/<family>.py``,
+``flops_per_seed``); what every family shares is here.
 
 ``gen_min_bytes`` is the least HBM traffic one worker's generation must
 move in a step: the CSR reads of every sampled neighbour, the sampled
@@ -21,23 +16,6 @@ def tree_levels(fanouts) -> list:
     for k in fanouts:
         levels.append(levels[-1] * k)
     return levels
-
-
-def model_flops_per_seed(fanouts, d_in: int, hidden: int,
-                         n_classes: int) -> dict:
-    """``{"forward": f, "backward": b}`` matrix-product FLOPs per seed."""
-    levels = tree_levels(fanouts)
-    depth = len(fanouts)
-    fwd = bwd = 0
-    din = d_in
-    for i in range(depth):
-        rows = sum(levels[:depth - i])        # levels 0 .. L-i
-        f = rows * 2 * (2 * din * hidden)     # w_self and w_nbr
-        fwd += f
-        bwd += f if i == 0 else 2 * f         # weights; inputs after layer 0
-        din = hidden
-    f = 2 * hidden * n_classes
-    return {"forward": fwd + f, "backward": bwd + 2 * f}
 
 
 def gen_min_bytes(fanouts, seeds_per_worker: int, n_workers: int,

@@ -1,14 +1,18 @@
 """One run of one cell: set-up, the measured window, the traced window,
 and the checks that decide ``correct``.
 
-Everything that belongs to a configuration, a cell or a per-layer metric
-is data found by name under the benchmark's directory:
-``configs/<name>.json``, ``workloads/<name>.json``, ``graphs/<name>.py``
-and ``metrics/<name>.py``.  The window drives the program's own training
-path as ``repro.launch.train.train_gcn`` composes it (``partition_edges``,
-``balance_table``, ``make_distributed_generator``, ``make_pipelined_step``
-over ``value_and_grad(gcn_loss)`` and ``adam_update``); the loop around it
-is the benchmark's and mirrors ``train_gcn``'s steady state.
+Everything that belongs to a configuration, a cell, a model family or
+a per-layer metric is data found by name under the benchmark's
+directory: ``configs/<name>.json``, ``workloads/<name>.json``,
+``graphs/<name>.py``, ``models/<family>.py`` (the family a
+configuration's ``model`` names: its reference equations, parameters and
+operation count) and ``metrics/<name>.py``.  The window drives the
+program's own training path as ``repro.launch.train.train_gcn`` composes
+it (``partition_edges``, ``balance_table``, ``make_distributed_generator``,
+``make_pipelined_step`` over ``value_and_grad`` of the loss that
+``repro.models.zoo.build`` gives the family, and ``adam_update``); the
+loop around it is the benchmark's and mirrors ``train_gcn``'s steady
+state.
 """
 from __future__ import annotations
 
@@ -55,16 +59,30 @@ def load_cell(name: str, root: Path = HERE):
     return wl, read_json(root, "configs", wl["config"])
 
 
-def metric_reader(name: str, root: Path = HERE):
-    """The ``read(ctx)`` function of ``metrics/<name>.py``."""
-    path = Path(root) / "metrics" / f"{name}.py"
+def _load_module(root: Path, kind: str, name: str, what: str):
+    """The module ``<root>/<kind>/<name>.py``."""
+    path = Path(root) / kind / f"{name}.py"
     if not path.is_file():
-        raise FileNotFoundError(f"no reader for metric {name!r} ({path})")
+        raise FileNotFoundError(f"no {what} {name!r} ({path})")
     spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+        f"chipbench_{kind}_" + name.replace(".", "_").replace("-", "_"),
+        path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name: str, root: Path = HERE):
+    """The ``read(ctx)`` function of ``metrics/<name>.py``."""
+    return _load_module(root, "metrics", name, "reader for metric").read
+
+
+def family_module(name: str, root: Path = HERE):
+    """The module ``models/<name>.py`` of a model family: ``init(key,
+    model, depth)``, ``to_program(flat, model, depth)``,
+    ``from_program(params)``, ``forward(flat, x_seed, x_hops, masks,
+    dtype)``, ``flops_per_seed(fanouts, model)`` and ``BLOCK``."""
+    return _load_module(root, "models", name, "model family")
 
 
 def cell_metrics(bench: dict, workload: str, kind: str) -> list:
@@ -126,7 +144,7 @@ def model_config(cfg: dict):
     from repro.core.config import ModelConfig
     m = dict(cfg["model"])
     m["fanouts"] = tuple(m["fanouts"])
-    return ModelConfig(name=cfg["name"], family="gcn", **m)
+    return ModelConfig(name=cfg["name"], **m)
 
 
 def train_config(cfg: dict):
@@ -141,6 +159,7 @@ class Setup:
     wl: dict
     cfg: dict
     dataset: object
+    family: types.ModuleType
     mesh: object
     gen_fn: object
     device_args: tuple
@@ -179,6 +198,7 @@ def build(wl: dict, cfg: dict, root: Path = HERE,
     w = int(wl["workers"])
     mesh = make_mesh((w,), ("data",))
     mcfg = model_config(cfg)
+    family = family_module(mcfg.family, root)
     t = time.perf_counter()
     ds = datasets.load(cfg["dataset"], root, data_dir or datasets.DATA_DIR)
     log(f"dataset {ds.n_nodes} nodes, {ds.n_edges} edges in "
@@ -195,47 +215,27 @@ def build(wl: dict, cfg: dict, root: Path = HERE,
     jax.block_until_ready(device_args)
     log(f"placement in {time.perf_counter() - t:.1f} s")
     tcfg = train_config(cfg)
-    step = jax.jit(make_pipelined_step(gen_fn, _train_fn(tcfg),
+    step = jax.jit(make_pipelined_step(gen_fn, _train_fn(tcfg, mcfg),
                                        cached=cache_cfg is not None))
     seed_nodes = np.flatnonzero(ds.degrees() > 0).astype(np.int32)
-    return Setup(wl, cfg, ds, mesh, gen_fn, device_args, cache_cfg, step,
-                 tcfg, seed_nodes)
+    return Setup(wl, cfg, ds, family, mesh, gen_fn, device_args, cache_cfg,
+                 step, tcfg, seed_nodes)
 
 
-def _train_fn(tcfg):
-    """``train_gcn``'s step 4: loss and gradient of ``gcn_loss``, then
-    ``adam_update`` (looked up at trace time)."""
+def _train_fn(tcfg, mcfg):
+    """``train_gcn``'s step 4: loss and gradient of the loss that
+    ``zoo.build`` gives the family, then ``adam_update`` (looked up at
+    trace time)."""
     import jax
-    from repro.models import gcn as gcn_mod
+    from repro.models import zoo
     from repro.train import optimizer
+    loss_fn = zoo.build(mcfg).loss
 
     def train_fn(params, opt, batch):
-        loss, grads = jax.value_and_grad(gcn_mod.gcn_loss)(params, batch)
+        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
         params, opt, _ = optimizer.adam_update(tcfg, params, grads, opt)
         return params, opt, loss
     return train_fn
-
-
-def to_program_params(flat: dict, depth: int):
-    """The program's ``GCNParams`` from the benchmark's flat dict."""
-    from repro.models.gcn import GCNLayerParams, GCNParams
-    layers = tuple(GCNLayerParams(flat[f"layers.{i}.w_self"],
-                                  flat[f"layers.{i}.w_nbr"],
-                                  flat[f"layers.{i}.b"])
-                   for i in range(depth))
-    return GCNParams(layers=layers, w_out=flat["w_out"], b_out=flat["b_out"])
-
-
-def from_program_params(params) -> dict:
-    """The benchmark's flat dict of host arrays from ``GCNParams``."""
-    out = {}
-    for i, lyr in enumerate(params.layers):
-        out[f"layers.{i}.w_self"] = np.asarray(lyr.w_self)
-        out[f"layers.{i}.w_nbr"] = np.asarray(lyr.w_nbr)
-        out[f"layers.{i}.b"] = np.asarray(lyr.b)
-    out["w_out"] = np.asarray(params.w_out)
-    out["b_out"] = np.asarray(params.b_out)
-    return out
 
 
 def _host_tree(batch) -> dict:
@@ -258,17 +258,14 @@ class Run:
         from repro.train.optimizer import init_adam
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        from chipbench import reference
-
         self.s = s
         k_params, k_rng = sub_seeds(seed, 2)
         depth = len(s.fanouts)
         m = s.cfg["model"]
-        flat = jax.jit(reference.init_params, static_argnums=(1, 2, 3, 4))(
-            jax.random.PRNGKey(k_params), depth, m["gcn_in_dim"],
-            m["gcn_hidden"], m["n_classes"])
+        flat = jax.jit(lambda key: s.family.init(key, m, depth))(
+            jax.random.PRNGKey(k_params))
         self.params0 = {k: np.asarray(v) for k, v in flat.items()}
-        params = to_program_params(flat, depth)
+        params = s.family.to_program(flat, m, depth)
         opt = init_adam(params)
         self.table = balance_table(s.seed_nodes, s.workers, seed)
         self.base_key = jax.random.PRNGKey(k_rng)
@@ -343,12 +340,12 @@ class Run:
         for i in range(self.RECORDED):
             self.losses.append(self.step())
             if i == 0:
-                m = from_program_params(self.carry[1].m)
+                m = self.s.family.from_program(self.carry[1].m)
                 self.grad1 = {k: v / (1.0 - self.s.tcfg.beta1)
                               for k, v in m.items()}
             if i + 1 < self.RECORDED:
                 self.batches.append(_host_tree(self.carry[2]))
-        self.p3 = from_program_params(self.carry[0])
+        self.p3 = self.s.family.from_program(self.carry[0])
 
     def final_batch(self):
         """The batch the last step generated, moved whole to the first
@@ -391,7 +388,8 @@ def check_run(run: Run, final) -> dict:
                                  x_hops, y))
     del final, x_seed, x_hops
     losses, grad1, p3 = reference.run_steps(
-        run.s.cfg["train"], run.params0, table, labels, run.batches)
+        run.s.cfg["train"], run.params0, table, labels, run.batches,
+        forward=run.s.family.forward, block=run.s.family.BLOCK)
     out.update(checks.model_gaps(run.losses, run.grad1, run.params0, run.p3,
                                  losses, grad1, p3))
     return out
@@ -523,8 +521,8 @@ def run_cell(name: str, seed: int, seconds: float, trace_on: bool, *,
                                   "unit": m["unit"]}
     else:
         ctx = types.SimpleNamespace(
-            wl=wl, cfg=cfg, workers=s.workers, seeds_per_worker=s.batch,
-            fanouts=s.fanouts, compile_s=compile_s,
+            wl=wl, cfg=cfg, family=s.family, workers=s.workers,
+            seeds_per_worker=s.batch, fanouts=s.fanouts, compile_s=compile_s,
             window_compiles=window_compiles, window_s=window_s,
             window_steps=steps, counters=counters, trace=summary,
             traced_steps=TRACE_STEPS, traced_distinct=distinct,

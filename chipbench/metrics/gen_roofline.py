@@ -10,7 +10,7 @@ def read(ctx):
     if ctx.trace is None or not ctx.trace.groups_s["gen"]:
         return None
     need = costs.gen_min_bytes(ctx.fanouts, ctx.seeds_per_worker,
-                               ctx.workers, ctx.cfg["model"]["gcn_in_dim"],
+                               ctx.workers, ctx.cfg["dataset"]["feat_dim"],
                                ctx.traced_distinct)
     t_min = need / ctx.peaks["hbm_bytes_per_s"]
     return 100.0 * t_min / (ctx.trace.groups_s["gen"] / ctx.traced_steps)

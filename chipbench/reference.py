@@ -1,21 +1,18 @@
-"""The plain reference of a training step: the GCN over a padded fanout
-tree, its loss and gradient, global-norm clipping and Adam, written in
-``jax.numpy`` from the model's equations and imported from nowhere in the
-program.
+"""The plain reference of a training step, shared by every model family:
+the padded tree's inputs, the loss and its gradient block by block,
+global-norm clipping and Adam, written in ``jax.numpy`` and imported from
+nowhere in the program.
 
-Model (GraphGen+ §3; Kipf & Welling with self and neighbour weights):
-tree level ``v`` holds ``x_v``; graph convolution ``i`` updates levels
-``0 .. L-i`` as ``relu(h_v W_self + mean_mask(h_{v+1}) W_nbr + b)``, where
-the mean runs over the fanout axis and counts only masked-in children
-(a parent with none gets 0).  After ``L`` convolutions the seed level goes
-through ``W_out, b_out``; the loss is the mean negative log-likelihood
-of the seed labels.  Adam as configured: global-norm clip, linear warm-up
-then cosine decay to a tenth, decoupled weight decay.
+A family (``models/<family>.py``) gives the model's equations as
+``forward(params, x_seed, x_hops, masks, dtype)`` and the seeds per block
+as ``BLOCK``; the loss is the mean negative log-likelihood of the seed
+labels.  Adam as configured: global-norm clip, linear warm-up then cosine
+decay to a tenth, decoupled weight decay.
 
-Parameters are a flat dict ``{"layers.<i>.w_self": ..., "w_out": ...}``.
-Everything is float32 at ``highest`` matmul precision, unless a lower
-``dtype`` is asked for: that is the control, which computes the forward
-and backward pass in ``bfloat16``.
+Parameters are a flat dict of float32 arrays.  Everything is float32 at
+``highest`` matmul precision, unless a lower ``dtype`` is asked for: that
+is the control, which computes the forward and backward pass in
+``bfloat16``.
 """
 from __future__ import annotations
 
@@ -25,54 +22,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-#: seeds per block of the reference's forward and backward pass, so
-#: that a block's padded tree fits beside the feature table
-BLOCK = 512
-
-
-def init_params(key, depth: int, d_in: int, hidden: int, n_classes: int):
-    """Glorot-uniform weights and zero biases, drawn from ``key``."""
-    shapes = {}
-    din = d_in
-    for i in range(depth):
-        shapes[f"layers.{i}.w_self"] = (din, hidden)
-        shapes[f"layers.{i}.w_nbr"] = (din, hidden)
-        shapes[f"layers.{i}.b"] = (hidden,)
-        din = hidden
-    shapes["w_out"] = (hidden, n_classes)
-    shapes["b_out"] = (n_classes,)
-    keys = jax.random.split(key, len(shapes))
-    out = {}
-    for k, (name, shape) in zip(keys, shapes.items()):
-        if len(shape) == 1:
-            out[name] = jnp.zeros(shape, jnp.float32)
-        else:
-            lim = math.sqrt(6.0 / (shape[0] + shape[1]))
-            out[name] = jax.random.uniform(k, shape, jnp.float32, -lim, lim)
-    return out
-
-
-def forward(params, x_seed, x_hops, masks, dtype=jnp.float32):
-    """Logits ``[b, n_classes]`` of the model on one padded tree."""
-    depth = len(x_hops)
-    p = {k: v.astype(dtype) for k, v in params.items()}
-    reps = [x_seed.astype(dtype)] + [x.astype(dtype) for x in x_hops]
-    for i in range(depth):
-        new = []
-        for v in range(depth - i):
-            m = masks[v].astype(dtype)
-            num = jnp.sum(reps[v + 1] * m[..., None], axis=-2)
-            den = jnp.maximum(jnp.sum(m, axis=-1, keepdims=True), 1)
-            agg = num / den
-            new.append(jax.nn.relu(reps[v] @ p[f"layers.{i}.w_self"]
-                                   + agg @ p[f"layers.{i}.w_nbr"]
-                                   + p[f"layers.{i}.b"]))
-        reps = new
-    return reps[0] @ p["w_out"] + p["b_out"]
-
-
-def nll_sum(params, x_seed, x_hops, masks, labels, dtype=jnp.float32):
-    """Summed negative log-likelihood of the seed labels."""
+def nll_sum(forward, params, x_seed, x_hops, masks, labels,
+            dtype=jnp.float32):
+    """Summed negative log-likelihood of the seed labels under the
+    family's ``forward``."""
     logits = forward(params, x_seed, x_hops, masks, dtype)
     logp = jax.nn.log_softmax(logits, axis=-1)
     return -jnp.sum(jnp.take_along_axis(logp, labels[:, None], axis=1))
@@ -85,20 +38,20 @@ def _tree(table, labels, seeds, hops, masks):
     return table[seeds], xs, labels[seeds]
 
 
-def make_block_grad(dtype=jnp.float32):
+def make_block_grad(forward, dtype=jnp.float32):
     """Jitted ``(params, table, labels, seeds, hops, masks) -> (nll sum,
     grads of it)`` over one block of seeds, at ``highest`` precision."""
     def block(params, table, labels, seeds, hops, masks):
         x_seed, x_hops, y = _tree(table, labels, seeds, hops, masks)
         with jax.default_matmul_precision("highest"):
-            s, g = jax.value_and_grad(nll_sum)(params, x_seed, x_hops,
-                                               masks, y, dtype)
+            s, g = jax.value_and_grad(nll_sum, argnums=1)(
+                forward, params, x_seed, x_hops, masks, y, dtype)
         return s.astype(jnp.float32), {k: v.astype(jnp.float32)
                                        for k, v in g.items()}
     return jax.jit(block)
 
 
-def loss_and_grad(block_fn, params, table, labels, batch, block: int = BLOCK,
+def loss_and_grad(block_fn, params, table, labels, batch, block: int,
                   rows=None):
     """Mean loss and its gradient over a batch of host arrays (``seeds``
     ``[B]``, ``hops``, ``masks``), summed block by block.  ``rows``
@@ -144,12 +97,13 @@ def adam(train: dict, params, grads, state, step: int):
     return new, (m, v)
 
 
-def run_steps(train: dict, params0, table, labels, batches,
-              block: int = BLOCK, dtype=jnp.float32, rows=None):
+def run_steps(train: dict, params0, table, labels, batches, *, forward,
+              block: int, dtype=jnp.float32, rows=None):
     """The reference's first ``len(batches)`` training steps from
-    ``params0``.  Returns ``(losses, first clipped gradient, params
-    after the last step)``, all on the host."""
-    block_fn = make_block_grad(dtype)
+    ``params0``, with the family's ``forward`` over blocks of ``block``
+    seeds.  Returns ``(losses, first clipped gradient, params after the
+    last step)``, all on the host."""
+    block_fn = make_block_grad(forward, dtype)
     params = {k: jnp.asarray(v, jnp.float32) for k, v in params0.items()}
     state = ({k: jnp.zeros_like(v) for k, v in params.items()},
              {k: jnp.zeros_like(v) for k, v in params.items()})

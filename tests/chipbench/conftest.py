@@ -1,6 +1,6 @@
 """Fixtures of the chip benchmark's tests: a tiny copy of the benchmark's
-directory (its graphs, metric readers and peaks, a tiny configuration
-and tiny cells) that the harness runs on the CPU."""
+directory (its graphs, model families, metric readers and peaks, a tiny
+configuration and tiny cells) that the harness runs on the CPU."""
 import contextlib
 import copy
 import io
@@ -45,7 +45,7 @@ def tiny_cell(workers: int) -> dict:
 
 def make_root(path: Path) -> Path:
     """A benchmark directory at ``path`` with the tiny cells."""
-    for sub in ("graphs", "metrics"):
+    for sub in ("graphs", "models", "metrics"):
         shutil.copytree(BENCH_DIR / sub, path / sub)
     peaks = json.loads((BENCH_DIR / "peaks.json").read_text())
     peaks["cpu"] = dict(peaks["TPU v5 lite"])

@@ -1,7 +1,7 @@
 """The operation and byte counts against hand counts."""
 import pytest
 
-from chipbench import costs
+from chipbench import costs, harness
 
 
 def test_tree_levels():
@@ -23,7 +23,9 @@ def test_tree_levels():
     ((8,), (128, 256, 64), 131_072 + 32_768, 131_072 + 65_536),
 ])
 def test_model_flops_per_seed(fanouts, dims, fwd, bwd):
-    f = costs.model_flops_per_seed(fanouts, *dims)
+    gcn = harness.family_module("gcn")
+    model = dict(zip(("gcn_in_dim", "gcn_hidden", "n_classes"), dims))
+    f = gcn.flops_per_seed(fanouts, model)
     assert f == {"forward": fwd, "backward": bwd}
 
 
