@@ -6,6 +6,7 @@ import dataclasses
 from ..core.config import ModelConfig, SHAPES, ShapeConfig
 from . import (
     deepseek_v2_236b,
+    graphgen_gat,
     graphgen_gcn,
     graphgen_gcn_deep,
     graphgen_sage,
@@ -26,11 +27,11 @@ REGISTRY: dict[str, ModelConfig] = {
         smollm_135m, smollm_360m, stablelm_12b, llama3_405b,
         qwen3_moe_30b_a3b, deepseek_v2_236b, llama32_vision_11b,
         whisper_small, mamba2_1p3b, zamba2_1p2b, graphgen_gcn,
-        graphgen_sage, graphgen_gcn_deep,
+        graphgen_sage, graphgen_gcn_deep, graphgen_gat,
     )
 }
 
-ASSIGNED_ARCHS = [n for n, c in REGISTRY.items() if c.family != "gcn"]
+ASSIGNED_ARCHS = [n for n, c in REGISTRY.items() if not c.is_gnn]
 
 # archs whose attention is quadratic-only: long_500k is skipped for them
 # (DESIGN.md §4); SSM/hybrid run it.
@@ -43,13 +44,15 @@ def get_config(name: str) -> ModelConfig:
 
 def smoke_config(cfg: ModelConfig) -> ModelConfig:
     """Reduced same-family config for CPU smoke tests."""
-    if cfg.family == "gcn":
+    if cfg.is_gnn:
         # shrink fanouts but keep the configured sampling depth; keep the
-        # cache tier on (tiny) when the full config enables it
+        # cache tier on (tiny) when the full config enables it, and a GAT
+        # multi-headed
         depth = max(len(cfg.fanouts), 1)
         small = ((4, 3) + (2,) * depth)[:depth]
         return dataclasses.replace(cfg, gcn_in_dim=16, gcn_hidden=32, n_classes=5,
                                    fanouts=small,
+                                   gat_heads=min(cfg.gat_heads, 2),
                                    cache_rows=min(cfg.cache_rows, 256),
                                    cache_l1_rows=min(cfg.cache_l1_rows, 32))
     hd = 16

@@ -23,12 +23,15 @@ VALID_CACHE_WIRES = ("dense", "compact")
 #: "host" keeps it in host RAM behind the L3 store (misses resolve via
 #: an async double-buffered host gather — core/host_store.py)
 VALID_FEATURE_STORES = ("device", "host")
+#: the graph neural network families: trained by ``train_gcn`` on
+#: GraphGen+ subgraph batches (``ModelConfig.is_gnn``), not on tokens
+GNN_FAMILIES = ("gcn", "gat")
 
 
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: str  # dense | moe | vlm | audio | ssm | hybrid | gcn
+    family: str  # dense | moe | vlm | audio | ssm | hybrid | gcn | gat
     n_layers: int = 0
     d_model: int = 0
     n_heads: int = 0
@@ -68,11 +71,13 @@ class ModelConfig:
     n_encoder_layers: int = 0
     n_audio_frames: int = 0
     d_audio: int = 0
-    # --- gcn (the paper's model) ---
+    # --- gcn (the paper's model) and gat: GNN_FAMILIES ---
     gcn_hidden: int = 0
     gcn_in_dim: int = 0
     n_classes: int = 0
     fanouts: Tuple[int, ...] = ()
+    gat_heads: int = 0         # gat: attention heads; each is
+                               # gcn_hidden // gat_heads wide
     # --- distributed feature-fetch policy (generation step 4) ---
     cache_rows: int = 0        # hot-node feature cache slots per worker
                                # (rounded UP to a power of two at
@@ -180,6 +185,11 @@ class ModelConfig:
             raise ValueError(
                 f"feature_store must be one of {VALID_FEATURE_STORES}, "
                 f"got {self.feature_store!r}")
+        if self.family == "gat" and (
+                self.gat_heads < 1 or self.gcn_hidden % self.gat_heads):
+            raise ValueError(
+                f"gat needs gat_heads >= 1 dividing gcn_hidden "
+                f"{self.gcn_hidden}, got {self.gat_heads}")
         if self.host_gather_depth not in (1, 2):
             raise ValueError(
                 f"host_gather_depth must be 1 (synchronous) or 2 "
@@ -209,6 +219,12 @@ class ModelConfig:
             cache_hit_cap=cand.hit_cap, capacity_slack=cand.capacity_slack)
 
     @property
+    def is_gnn(self) -> bool:
+        """Whether the family is a graph neural network (``GNN_FAMILIES``),
+        trained and served on subgraph batches."""
+        return self.family in GNN_FAMILIES
+
+    @property
     def resolved_head_dim(self) -> int:
         """Per-head attention dim: ``head_dim`` when set explicitly,
         else ``d_model // n_heads``."""
@@ -223,6 +239,12 @@ class ModelConfig:
             depth = max(len(self.fanouts), 1)
             # per conv layer: self + neighbor transforms + bias
             total = 2 * d * h + h + (depth - 1) * (2 * h * h + h)
+            return total + h * self.n_classes + self.n_classes
+        if self.family == "gat":
+            d, h = self.gcn_in_dim, self.gcn_hidden
+            depth = max(len(self.fanouts), 1)
+            # per layer: W, a_src and a_dst (h each), bias
+            total = d * h + 3 * h + (depth - 1) * (h * h + 3 * h)
             return total + h * self.n_classes + self.n_classes
         hd = self.resolved_head_dim
         emb = self.vocab_size * self.d_model

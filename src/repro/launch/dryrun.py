@@ -72,10 +72,11 @@ def lower_gcn_cell(rec: dict, arch: str, multi_pod: bool,
     generation+training step on a 530M-node / 5B-edge graph (the paper's
     evaluation graph).  The sampling depth comes from the arch config —
     2-hop (40, 20) for the paper cell, 1-hop for graphgen-sage, 3-hop for
-    graphgen-gcn-deep (~1.7M padded nodes per iteration at (40, 20)).
-    Generation shards over 'data' (the worker axis); the small GCN
-    replicates over 'model'.  When the config enables the hot-node feature
-    cache, its per-worker state rides in the pipelined carry —
+    graphgen-gcn-deep (~1.7M padded nodes per iteration at (40, 20)),
+    and the model (init and loss) from ``zoo.build``, at the arch's own
+    widths.  Generation shards over 'data' (the worker axis); the small
+    GNN replicates over 'model'.  When the config enables the hot-node
+    feature cache, its per-worker state rides in the pipelined carry —
     ``(params, opt, batch, cache)`` — and must partition/compile too.
 
     With ``feature_store="host"`` the cell lowers the L3 path: the
@@ -95,14 +96,13 @@ def lower_gcn_cell(rec: dict, arch: str, multi_pod: bool,
     from ..core.host_store import HostMissRequest
     from ..core.pipeline import make_host_consume_step, make_pipelined_step
     from ..graph.subgraph import batch_specs, slots_per_seed
-    from ..models import gcn as gcn_mod
+    from ..models import zoo
     from ..train.optimizer import adam_update, init_adam
 
     mesh = make_production_mesh(multi_pod=multi_pod)
     axis = "data"
     w = mesh.shape[axis]
-    cfg = dataclasses.replace(get_config(arch), gcn_in_dim=128,
-                              gcn_hidden=256, n_classes=64)
+    cfg = get_config(arch)
     if cache_rows is not None:
         cfg = dataclasses.replace(cfg, cache_rows=cache_rows)
     if cache_mode is not None:
@@ -136,12 +136,14 @@ def lower_gcn_cell(rec: dict, arch: str, multi_pod: bool,
                                collect_stats=collect_stats)
     tcfg = TrainConfig()
 
+    model = zoo.build(cfg)
+
     def train_fn(params, opt, batch):
-        loss, grads = jax.value_and_grad(gcn_mod.gcn_loss)(params, batch)
+        loss, grads = jax.value_and_grad(model.loss)(params, batch)
         params, opt, _ = adam_update(tcfg, params, grads, opt)
         return params, opt, loss
 
-    params = jax.eval_shape(lambda: gcn_mod.init_gcn(cfg, jax.random.PRNGKey(0)))
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
     opt = jax.eval_shape(lambda: init_adam(params))
     batch0 = batch_specs(w * b, fanouts, cfg.gcn_in_dim, n_workers=w)
     if collect_stats:
@@ -259,7 +261,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
         "mesh": "2x16x16" if multi_pod else "16x16",
         "variant": variant,
     }
-    if cfg.family == "gcn":
+    if cfg.is_gnn:
         rec["kind"] = "train"
         return lower_gcn_cell(rec, arch, multi_pod, merge_mode=gen_merge,
                               cache_rows=cache_rows, cache_mode=cache_mode,

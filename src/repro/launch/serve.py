@@ -10,7 +10,7 @@ Requests flow through three stages:
    smallest bucket in a small shape ladder, and the ladder is compiled
    once at startup, so a request NEVER lands on a re-JIT (the latency
    killer the JIT-compiled-inference paper names);
-3. **read-mostly fetch** — subgraph generation + a forward-only GCN run
+3. **read-mostly fetch** — subgraph generation + a forward-only GNN run
    against the tiered L1/L2 feature cache in its frozen serve view
    (``CacheConfig.serve_view()``): probes serve hits, the admit stage is
    the identity, and the warm state — restored from a training
@@ -52,7 +52,6 @@ from ..core.generation import (make_distributed_generator,  # noqa: E402
 from ..core.partition import partition_edges            # noqa: E402
 from ..graph.synthetic import (node_features, node_labels,  # noqa: E402
                                powerlaw_graph)
-from ..models import gcn as gcn_mod                     # noqa: E402
 from ..models import zoo                                # noqa: E402
 from ..train import checkpoint as ckpt                  # noqa: E402
 from .compile_cache import enable_compile_cache        # noqa: E402
@@ -126,12 +125,13 @@ class GraphServer:
     Holds ONE warm cache state and one parameter tree, both read-only,
     and answers ``serve(seed_ids) -> class predictions`` by padding the
     request to its ladder bucket and running the forward-only program
-    (frozen-cache subgraph generation + GCN forward + argmax) compiled
-    for that bucket.  Call :meth:`warmup` once at startup to compile
+    (frozen-cache subgraph generation + the GNN's ``logits`` + argmax)
+    compiled for that bucket; ``logits(params, batch)`` is the family's,
+    from ``zoo.build``.  Call :meth:`warmup` once at startup to compile
     every bucket; after that the request path never traces —
     :meth:`compile_count` is the probe that proves it."""
 
-    def __init__(self, gen_fn, device_args, params, cache, *,
+    def __init__(self, gen_fn, device_args, params, cache, *, logits,
                  buckets=DEFAULT_BUCKETS, n_workers: int, seed: int = 0):
         self._buckets = tuple(sorted(set(int(b) for b in buckets)))
         if not self._buckets or self._buckets[0] <= 0:
@@ -150,8 +150,8 @@ class GraphServer:
                 batch = gen_fn(device_args, seeds, rng, cache)
             else:
                 batch = gen_fn(device_args, seeds, rng)
-            logits = gcn_mod.gcn_forward(params, batch)
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return jnp.argmax(logits(params, batch),
+                              axis=-1).astype(jnp.int32)
 
         self._step = jax.jit(_step)
 
@@ -239,7 +239,8 @@ def serve_gcn(args) -> dict:
     part = partition_edges(graph, w)
     feats = node_features(graph.n_nodes, cfg.gcn_in_dim, args.seed)
     labels = node_labels(graph.n_nodes, cfg.n_classes, args.seed)
-    params = gcn_mod.init_gcn(cfg, jax.random.PRNGKey(args.seed))
+    model = zoo.build(cfg)
+    params = model.init(jax.random.PRNGKey(args.seed))
     # degree-ranked hot head: warmup population AND the synthetic request
     # stream's Zipf rank -> id mapping
     head_order = np.argsort(
@@ -280,7 +281,8 @@ def serve_gcn(args) -> dict:
             mesh, part, feats, labels, fanouts=cfg.fanouts)
 
     server = GraphServer(gen_serve, device_args, params, cache,
-                         buckets=buckets, n_workers=w, seed=args.seed)
+                         logits=model.logits, buckets=buckets, n_workers=w,
+                         seed=args.seed)
     server.warmup()
     startup_compiles = server.compile_count()
     print(f"bucket ladder {server.buckets} compiled at startup "
@@ -383,8 +385,8 @@ def serve_lm(args) -> dict:
 
 
 def main() -> None:
-    """CLI entry: dispatch on the arch family — ``gcn`` archs get the
-    graph-serving tier, zoo archs the LM decode driver."""
+    """CLI entry: dispatch on the arch family — GNN archs (``is_gnn``)
+    get the graph-serving tier, zoo archs the LM decode driver."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m")
     ap.add_argument("--smoke", action="store_true")
@@ -416,7 +418,7 @@ def main() -> None:
                          "instead of sweeping")
     args = ap.parse_args()
     enable_compile_cache()
-    if get_config(args.arch).family == "gcn":
+    if get_config(args.arch).is_gnn:
         rec = serve_gcn(args)
         if rec["request_path_compiles"]:
             raise SystemExit(

@@ -2,10 +2,11 @@
 
 Two modes, selected by --arch:
 
-* ``graphgen-gcn`` (the paper): synthetic power-law graph -> coordinator
-  partitioning -> balance table -> synchronized distributed subgraph
-  generation + in-memory GCN training (the GraphGen+ pipeline), with
-  checkpoint/restart and optional failure injection.
+* ``graphgen-gcn`` (the paper) or any other GNN arch (``graphgen-gat``):
+  synthetic power-law graph -> coordinator partitioning -> balance table
+  -> synchronized distributed subgraph generation + in-memory GNN
+  training (the GraphGen+ pipeline), with checkpoint/restart and optional
+  failure injection.
 
 * any LM arch id: reduced-config training on synthetic token batches using
   the same substrate (AdamW, microbatching, checkpointing).
@@ -37,7 +38,6 @@ from ..core.generation import make_distributed_generator  # noqa: E402
 from ..core.partition import partition_edges            # noqa: E402
 from ..core.pipeline import make_pipelined_step         # noqa: E402
 from ..graph.synthetic import node_features, node_labels, powerlaw_graph  # noqa: E402
-from ..models import gcn as gcn_mod                     # noqa: E402
 from ..models import zoo                                # noqa: E402
 from ..train import checkpoint as ckpt                  # noqa: E402
 from ..train.optimizer import adam_update, init_adam    # noqa: E402
@@ -179,7 +179,8 @@ def warm_capacity(miss_peak: int, w: int, slack: float, rows: int,
 
 
 def train_gcn(args) -> dict:
-    """Train the GCN of ``args.arch`` on a synthetic power-law graph.
+    """Train the GNN of ``args.arch`` (init and loss from ``zoo.build``)
+    on a synthetic power-law graph.
 
     Returns the losses, padded nodes per iteration, wall and set-up
     seconds, the slack used and the requests dropped over every trained
@@ -358,11 +359,12 @@ def train_gcn(args) -> dict:
         print(line)
     tcfg = TrainConfig(learning_rate=args.lr, total_steps=args.steps,
                        checkpoint_every=args.ckpt_every)
-    params = gcn_mod.init_gcn(cfg, jax.random.PRNGKey(args.seed))
+    model = zoo.build(cfg)
+    params = model.init(jax.random.PRNGKey(args.seed))
     opt = init_adam(params)
 
     def train_fn(params, opt, batch):                      # step 4
-        loss, grads = jax.value_and_grad(gcn_mod.gcn_loss)(params, batch)
+        loss, grads = jax.value_and_grad(model.loss)(params, batch)
         params, opt, _ = adam_update(tcfg, params, grads, opt)
         return params, opt, loss
 
@@ -695,7 +697,7 @@ def main() -> None:
     if args.cache_probe_impl != "jnp":
         from ..core.feature_cache import set_probe_impl
         set_probe_impl(args.cache_probe_impl)
-    if get_config(args.arch).family == "gcn":
+    if get_config(args.arch).is_gnn:
         train_gcn(args)
     else:
         train_lm(args)

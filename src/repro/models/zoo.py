@@ -1,6 +1,7 @@
 """Architecture zoo: uniform build/init/loss/decode API over all assigned
-architectures + the paper's GCN, plus the sharding-spec rules that map any
-param/cache pytree onto the production mesh.
+architectures + the GNN families (the paper's GCN, and GAT), plus the
+sharding-spec rules that map any param/cache pytree onto the production
+mesh.
 """
 from __future__ import annotations
 
@@ -11,7 +12,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.config import ModelConfig, ShapeConfig
-from . import deepseek, gcn, hybrid, moe, ssm, transformer, vlm, whisper
+from . import deepseek, gat, gcn, hybrid, moe, ssm, transformer, vlm, whisper
 from . import layers as L
 
 
@@ -21,6 +22,7 @@ class ModelAPI(NamedTuple):
     loss: Callable[[Any, Any], jax.Array]
     decode: Optional[Callable]        # (params, cache, tokens, pos) -> (logits, cache)
     init_cache: Optional[Callable]    # (batch, seq) -> cache
+    logits: Optional[Callable] = None  # GNN: (params, SubgraphBatch) -> logits
 
 
 _FAMILIES = {
@@ -46,6 +48,9 @@ def _family_key(cfg: ModelConfig) -> str:
 
 
 def build(cfg: ModelConfig) -> ModelAPI:
+    """The family's functions bound to ``cfg``.  A GNN family gives
+    ``init``, ``loss`` and ``logits`` over ``SubgraphBatch`` and no
+    decode; each is looked up in its module when called."""
     if cfg.family == "gcn":
         return ModelAPI(
             cfg=cfg,
@@ -53,6 +58,16 @@ def build(cfg: ModelConfig) -> ModelAPI:
             loss=lambda p, b: gcn.gcn_loss(p, b),
             decode=None,
             init_cache=None,
+            logits=lambda p, b: gcn.gcn_forward(p, b),
+        )
+    if cfg.family == "gat":
+        return ModelAPI(
+            cfg=cfg,
+            init=lambda key: gat.init_gat(cfg, key),
+            loss=lambda p, b: gat.gat_loss(p, b),
+            decode=None,
+            init_cache=None,
+            logits=lambda p, b: gat.gat_forward(p, b),
         )
     init_f, loss_f, dec_f, cache_f = _FAMILIES[_family_key(cfg)]
     return ModelAPI(

@@ -64,7 +64,8 @@ def _tiny_serving_stack(cached: bool):
                                gcn_hidden=8, n_classes=C, fanouts=(4, 3))
     params = gcn_mod.init_gcn(mcfg, jax.random.PRNGKey(1))
     server = GraphServer(out[0], out[1], params, None,
-                         buckets=(4, 8), n_workers=1)
+                         logits=gcn_mod.gcn_forward, buckets=(4, 8),
+                         n_workers=1)
     return server, N
 
 
