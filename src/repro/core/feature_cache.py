@@ -83,7 +83,7 @@ fetched table rows, and features are immutable during an epoch.
 """
 from __future__ import annotations
 
-from typing import NamedTuple, Optional, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -627,22 +627,44 @@ def cache_probe(
         raise ValueError(f"cfg.n_rows {cfg.n_rows} != cache state rows "
                          f"{cache.n_rows}: probing under a mismatched "
                          f"layout silently loses residents")
-    a = cfg.assoc
     if (impl or _PROBE_IMPL) == "pallas":
         from ..kernels.ops import cache_probe_gather
         hit, rows = cache_probe_gather(cache.keys, cache.rows, ids,
-                                       assoc=a, use_kernel=True)
+                                       assoc=cfg.assoc, use_kernel=True)
     else:
-        sets = hash_slots(ids, cfg.n_sets)
-        slots = sets[:, None] * a + jnp.arange(a, dtype=jnp.int32)[None, :]
-        match = cache.keys[slots] == ids[:, None]           # [R, A]
-        hit = match.any(axis=-1)
-        way = jnp.argmax(match, axis=-1).astype(jnp.int32)  # first match
-        rows = jnp.where(hit[:, None], cache.rows[sets * a + way], 0)
+        hit, slot = cache_lookup(cache, ids, cfg=cfg)
+        rows = jnp.where(hit[:, None], cache.rows[slot], 0)
     if valid is not None:
         hit = jnp.logical_and(hit, valid)
         rows = jnp.where(hit[:, None], rows, 0)
     return hit, rows
+
+
+def cache_lookup(
+    cache: FeatureCache,
+    ids: jax.Array,
+    valid: Optional[jax.Array] = None,
+    *,
+    cfg: CacheConfig,
+) -> Tuple[jax.Array, jax.Array]:
+    """The probe's index half: ``(hit [R] bool, slot [R] int32)``, where
+    ``cache.rows[slot]`` is a hit's row (``slot`` is some in-range slot
+    of the id's set where it missed).  ``cache_probe`` gathers those
+    rows; a caller that reads them later, straight into where they go,
+    gathers them itself.  ``cfg`` as for ``cache_probe``."""
+    if cfg.n_rows != cache.n_rows:
+        raise ValueError(f"cfg.n_rows {cfg.n_rows} != cache state rows "
+                         f"{cache.n_rows}: probing under a mismatched "
+                         f"layout silently loses residents")
+    a = cfg.assoc
+    sets = hash_slots(ids, cfg.n_sets)
+    slots = sets[:, None] * a + jnp.arange(a, dtype=jnp.int32)[None, :]
+    match = cache.keys[slots] == ids[:, None]           # [R, A]
+    hit = match.any(axis=-1)
+    way = jnp.argmax(match, axis=-1).astype(jnp.int32)  # first match
+    if valid is not None:
+        hit = jnp.logical_and(hit, valid)
+    return hit, sets * a + way
 
 
 def cache_insert(
@@ -652,19 +674,34 @@ def cache_insert(
     should: jax.Array,
     cfg: CacheConfig,
 ) -> Tuple[FeatureCache, jax.Array]:
-    """Offer [R] fetched rows to the cache; returns (new_cache, n_inserted).
+    """Offer [R] fetched rows [R, D] to the cache; returns (new_cache,
+    n_inserted).  ``cache_insert_from`` with the offers' rows at hand."""
+    return cache_insert_from(cache, ids, lambda w: rows[w], should, cfg)
 
+
+def cache_insert_from(
+    cache: FeatureCache,
+    ids: jax.Array,
+    row_of: Callable[[jax.Array], jax.Array],
+    should: jax.Array,
+    cfg: CacheConfig,
+) -> Tuple[FeatureCache, jax.Array]:
+    """Offer [R] ids to the cache; returns (new_cache, n_inserted).
+
+    ``row_of`` maps the C winning offer indices [C] to their rows [C, D],
+    so a caller that can read the few winners' rows where they live
+    builds no row per offer (``cache_insert`` indexes [R, D] offers).
     ``cfg`` is REQUIRED and must match the config every probe of this
     state uses (the slot layout is a property of the populated state).
     ``should`` masks the offers (missed AND actually served — a
     capacity-dropped zero row must never be cached).  Admission: a
     candidate id is installed once its counter reaches ``cfg.admit``
-    (``admit <= 1`` degrades to always-insert).  Way choice inside a set:
-    an id already tracked as a candidate keeps its way; a new candidate
-    takes the way with the smallest admission counter, empty ways first —
-    the counter IS the victim policy, so contended candidates keep their
-    progress.  Distinct ids colliding on one slot within a single batch
-    are resolved to ONE winner (highest request index) *before* any
+    (``admit <= 1`` degrades to always-insert).  Way choice inside a
+    set: an id already tracked as a candidate keeps its way; a new
+    candidate takes the way with the smallest admission counter, empty
+    ways first — the counter IS the victim policy, so contended
+    candidates keep their progress.  Distinct ids colliding on one slot
+    within a single batch are resolved to ONE winner (highest request index) *before* any
     write: without a pre-resolved winner, ``keys[s]`` could take id A
     while ``rows[s]`` takes B's row and every later probe of A would
     silently return B's features.
@@ -753,7 +790,7 @@ def cache_insert(
     install = jnp.logical_and(offer, new_count >= admit)
     new = FeatureCache(
         keys=jnp.where(install, ids_w, cache.keys),
-        rows=jnp.where(install[:, None], rows[w].astype(cache.rows.dtype),
+        rows=jnp.where(install[:, None], row_of(w).astype(cache.rows.dtype),
                        cache.rows),
         tags=jnp.where(offer, ids_w, cache.tags),
         counts=jnp.where(offer, new_count, cache.counts),

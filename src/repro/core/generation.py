@@ -114,7 +114,8 @@ from jax import shard_map
 
 from ..graph.subgraph import SubgraphBatch
 from .feature_cache import (CacheConfig, CacheStats, FeatureCache,
-                            TieredCache, cache_insert, cache_probe,
+                            TieredCache, cache_insert, cache_insert_from,
+                            cache_lookup, cache_probe,
                             compact_hit_rows, expand_hit_rows,
                             get_probe_impl, hit_bitmap_words,
                             init_cache_state, pack_hit_bitmap,
@@ -577,8 +578,6 @@ class _ShardedTier:
     @staticmethod
     @_staged("cache_insert")
     def admit(cache, cfg, probe, ids, fetched, should, axis_name, w):
-        if w == 1:
-            return cache_insert(cache, ids, fetched, should, cfg)
         plan, recv = probe.ctx
         return _shard_admit(cache, cfg, plan, recv, fetched, should,
                             axis_name, w)
@@ -617,12 +616,8 @@ class _TieredTier:
     @_staged("cache_insert")
     def admit(cache, cfg, probe, ids, fetched, should, axis_name, w):
         plan, recv, l2_hit = probe.ctx
-        if w == 1:
-            new_l2, n_l2 = cache_insert(cache.l2, ids, fetched, should,
-                                        cfg.l2_config())
-        else:
-            new_l2, n_l2 = _shard_admit(cache.l2, cfg.l2_config(), plan,
-                                        recv, fetched, should, axis_name, w)
+        new_l2, n_l2 = _shard_admit(cache.l2, cfg.l2_config(), plan, recv,
+                                    fetched, should, axis_name, w)
         # L1 promotion is strictly L2 -> L1: only rows the L2 tier SERVED
         # this round (verbatim table copies that already survived the L2's
         # frequency admission — the proven-hot population) are offered to
@@ -809,6 +804,139 @@ def _host_fetch(ids, axis_name, capacity_slack, capacity, cache, cache_cfg,
     return out, new_cache, stats, cstats, req
 
 
+def _index_first(w: int, cache, host: bool) -> bool:
+    """Whether a fetch resolves sources first (``_local_fetch``): one
+    worker, a cache and the device store.  Elsewhere rows arrive per
+    distinct id by nature (over the wire at W > 1, or from the host
+    store), so those paths keep a buffer of them."""
+    return w == 1 and cache is not None and not host
+
+
+class _LocalFetch(NamedTuple):
+    """Where every request slot's row lives, as ``_local_fetch`` resolves
+    it, and the fetch's state and counters."""
+    src: jax.Array         # [R] a request slot's table row (>= 0), or
+                           # -1 - its row in ``cache_rows`` for a hit
+    cache_rows: jax.Array  # [C, D] the cache's rows (tiered: L1's, then L2's)
+    cache: object          # the state after admission
+    stats: FetchStats
+    cstats: CacheStats
+
+
+def _local_fetch(table_local: jax.Array, ids: jax.Array, cache,
+                 cfg: CacheConfig) -> _LocalFetch:
+    """The cached fetch at W == 1 on the device store, index first.
+
+    It serves the requests ``fetch_rows`` serves from the same sources,
+    with the same counters, and leaves the same cache state, but builds
+    no row per distinct id: each distinct id resolves to a source with
+    int32 work (its cache row for a hit, L1 before L2 in tiered mode;
+    its table row for a miss), and one int gather through ``inverse``
+    carries the source to the request slots, where ``_slot_rows`` reads
+    each row once, straight into the shape it is wanted in.  The inserts
+    read their C winners' rows where those live: a served miss's in the
+    table, an L1 promotion's in the L2 rows the probe read (the L2 as it
+    was before this step's insert).
+    """
+    r = ids.shape[0]
+    n_rows = table_local.shape[0]
+    req_ids, inverse, req_valid, _ = dedup_requests(ids)
+    tiered = cfg.mode == "tiered"
+    with _stage("cache_probe"):
+        if tiered:
+            l1_hit, l1_slot = cache_lookup(cache.l1, req_ids, req_valid,
+                                           cfg=cfg.l1_config())
+            l2_hit, l2_slot = cache_lookup(cache.l2, req_ids, req_valid,
+                                           cfg=cfg.l2_config())
+            local = jnp.logical_and(l2_hit, ~l1_hit)    # served by the L2
+            hit = jnp.logical_or(l1_hit, local)
+            slot = jnp.where(l1_hit, l1_slot, cfg.l1_rows + l2_slot)
+            cache_rows = jnp.concatenate([cache.l1.rows, cache.l2.rows])
+        else:
+            hit, slot = cache_lookup(cache, req_ids, req_valid, cfg=cfg)
+            l1_hit, local = _zeros_like_hits(req_ids), hit
+            cache_rows = cache.rows
+        src_u = jnp.where(hit, -1 - slot, jnp.clip(req_ids, 0, n_rows - 1))
+    with _stage("owner_fetch"):
+        miss = jnp.logical_and(req_valid, ~hit)
+        n_routed = jnp.sum(miss).astype(jnp.int32)
+    n_ins = jnp.int32(0)
+    if not cfg.frozen:          # the serve view admits nothing
+        def table_rows(w):
+            return table_local[jnp.clip(req_ids[w], 0, n_rows - 1)]
+
+        with _stage("cache_insert"):
+            if tiered:
+                new_l2, n_l2 = cache_insert_from(cache.l2, req_ids,
+                                                 table_rows, miss,
+                                                 cfg.l2_config())
+                new_l1, n_l1 = cache_insert_from(
+                    cache.l1, req_ids, lambda w: cache.l2.rows[l2_slot[w]],
+                    local, cfg.l1_config())
+                cache, n_ins = TieredCache(l1=new_l1, l2=new_l2), n_l2 + n_l1
+            else:
+                cache, n_ins = cache_insert_from(cache, req_ids, table_rows,
+                                                 miss, cfg)
+    with _stage("slot_scatter"):
+        src = src_u[inverse]
+    row_bytes = table_local.shape[1] * jnp.dtype(table_local.dtype).itemsize
+    with _stage("cache_probe"):
+        n_hits = jnp.sum(hit).astype(jnp.int32)
+        n_l1 = jnp.sum(l1_hit).astype(jnp.int32)
+        n_local = jnp.sum(local).astype(jnp.int32)
+        z = jnp.int32(0)
+        cstats = CacheStats(
+            n_hits=n_hits, n_misses=n_routed, n_inserted=n_ins,
+            bytes_saved=(n_l1 + n_local) * row_bytes, n_local_hits=n_local,
+            n_shard_hits=n_hits - n_l1 - n_local, n_l1_hits=n_l1,
+            n_probe_demoted=z, probe_hit_peak=z, n_l3_hits=z)
+    # a local gather serves every distinct id: no request slot is dropped
+    stats = FetchStats(jnp.int32(r), n_routed, z, z, z)
+    return _LocalFetch(src, cache_rows, cache, stats, cstats)
+
+
+def _tiled_order(shape) -> Tuple[int, ...]:
+    """The axes of an array ``shape + (D,)``, outermost first, as a TPU
+    lays it out by default: its (8, 128) tiles pad the axis next to D, so
+    that place goes to the axis that pads least (the innermost of those
+    that tie) and the others keep their order.  This mirrors the TPU
+    compiler's layout choice, which no API reports: if it changes, a
+    relayout copy comes back with no error.  The guards are
+    ``tests/test_tpu_compile.py``: ``test_tiled_order_is_the_v5e_default_layout``
+    and the W=1 generator tests, which find no copy of rows in any
+    cell's compiled generator."""
+    if len(shape) < 2:
+        return tuple(range(len(shape)))
+    waste = [(-(-n // 8) * 8 - n) / (-(-n // 8) * 8) for n in shape]
+    inner = min(range(len(shape)), key=lambda a: (waste[a], -a))
+    return tuple(a for a in range(len(shape)) if a != inner) + (inner,)
+
+
+def _slot_rows(table_local: jax.Array, cache_rows: jax.Array,
+               src: jax.Array, mask: Optional[jax.Array] = None):
+    """The rows of request slots ``src`` (``_LocalFetch.src``, any shape):
+    ``src.shape + (D,)``, a hit's from ``cache_rows`` and a miss's from
+    the table, zero where ``mask`` is False.  The gathers run over the
+    slots in the order the result is laid out in (``_tiled_order``), so
+    that putting them in ``src``'s shape moves no data."""
+    order = _tiled_order(src.shape)
+    back = tuple(np.argsort(order)) + (len(order),)
+    src = jnp.transpose(src, order)
+    with _stage("owner_fetch"):
+        table_row = table_local[jnp.maximum(src, 0)]
+    with _stage("slot_scatter"):
+        cache_row = cache_rows[jnp.maximum(-1 - src, 0)]
+        hit = src < 0
+        from_table = ~hit
+        if mask is not None:
+            mask = jnp.transpose(mask, order)
+            hit = jnp.logical_and(hit, mask)
+            from_table = jnp.logical_and(from_table, mask)
+        rows = jnp.where(hit[..., None], cache_row,
+                         jnp.where(from_table[..., None], table_row, 0))
+        return jnp.transpose(rows, back)
+
+
 def fetch_rows(
     table_local: jax.Array,
     ids: jax.Array,
@@ -900,7 +1028,9 @@ def fetch_rows(
     W == 1 the fetch degenerates to a local gather (no routing; sharded
     mode degenerates to replicated — the single worker owns every shard —
     and ``n_unique`` still reports the would-route distinct/miss count so
-    single-device runs measure the same wire-slot telemetry).
+    single-device runs measure the same wire-slot telemetry); cached, on
+    the device store, it resolves sources first and reads each slot's
+    row from where it lives (``_local_fetch``).
     """
     if cache is not None and not dedup:
         raise ValueError("the cache front end requires dedup=True")
@@ -966,6 +1096,10 @@ def fetch_rows(
     if host:
         return _host_fetch(ids, axis_name, capacity_slack, capacity,
                            cache, cache_cfg, host_admit, d, dtype, w)
+    if _index_first(w, cache, host):
+        local = _local_fetch(table_local, ids, cache, cache_cfg)
+        out = _slot_rows(table_local, local.cache_rows, local.src)
+        return out, local.cache, local.stats, local.cstats
     if w == 1 and cache is None:
         with _stage("owner_fetch"):
             out = table_local[jnp.clip(ids, 0, rows - 1)]
@@ -1006,13 +1140,8 @@ def fetch_rows(
     with _stage("owner_fetch"):
         route_valid = (req_valid if probe is None
                        else jnp.logical_and(req_valid, ~probe.hit))
-        if w == 1:
-            fetched = table_local[jnp.clip(req_ids, 0, rows - 1)]
-            fetched = jnp.where(route_valid[:, None], fetched, 0)
-            served_r = route_valid
-        else:
-            fetched, served_r = _routed_fetch(
-                table_local, req_ids, route_valid, axis_name, cap, w, rows)
+        fetched, served_r = _routed_fetch(
+            table_local, req_ids, route_valid, axis_name, cap, w, rows)
         n_routed = jnp.sum(route_valid).astype(jnp.int32)
     # --- merge hits back, offer served misses for admission --------------
     new_cache = None
@@ -1059,6 +1188,17 @@ def fetch_rows(
     if return_stats:
         return out, stats
     return out
+
+
+def _split_levels(flat: jax.Array, b: int, masks):
+    """Split ``flat`` [R, ...], in the order ``need`` lists the tree's
+    slots, into the seeds' part [b, ...] and one part per hop shaped like
+    that hop's mask."""
+    parts, off = [], b
+    for m in masks:
+        parts.append(flat[off:off + m.size].reshape(m.shape + flat.shape[1:]))
+        off += m.size
+    return flat[:b], parts
 
 
 def _worker_generate(
@@ -1176,12 +1316,17 @@ def _worker_generate(
     with _stage("dedup"):
         need = jnp.concatenate([seeds] + [h.reshape(-1) for h in hops])
     host = feature_store == "host"
-    req = None
+    req = local = None
     if cache is not None and host:
         feats, cache, fstats, cstats, req = fetch_rows(
             x_local, need, axis_name, capacity_slack=capacity_slack,
             capacity=fetch_capacity, cache=cache, cache_cfg=cache_cfg,
             store="host", feat_dim=feat_dim, host_admit=host_admit)
+        n_hits, n_misses = cstats.n_hits, cstats.n_misses
+        n_demoted = cstats.n_probe_demoted
+    elif _index_first(lax.axis_size(axis_name), cache, host):
+        local = _local_fetch(x_local, need, cache, cache_cfg)
+        cache, fstats, cstats = local.cache, local.stats, local.cstats
         n_hits, n_misses = cstats.n_hits, cstats.n_misses
         n_demoted = cstats.n_probe_demoted
     elif cache is not None:
@@ -1204,17 +1349,19 @@ def _worker_generate(
                                    return_stats=True)
         n_hits, n_misses = jnp.int32(0), fstats.n_unique
         n_demoted = jnp.int32(0)
-    d = x_local.shape[1] if x_local is not None else feat_dim
-    with _stage("slot_scatter"):
-        x_seed = feats[:b]
-        x_hops = []
-        off = b
-        n = b
-        for level, k in enumerate(fanouts):
-            n *= k
-            x = feats[off:off + n].reshape(masks[level].shape + (d,))
-            x_hops.append(x * masks[level][..., None])
-            off += n
+    if local is not None:
+        # each slot's row is gathered once, into its level's shape, and
+        # the select that picks it zeroes padded slots
+        with _stage("slot_scatter"):
+            src_seed, src_hops = _split_levels(local.src, b, masks)
+        x_seed = _slot_rows(x_local, local.cache_rows, src_seed)
+        x_hops = [_slot_rows(x_local, local.cache_rows, src, m)
+                  for src, m in zip(src_hops, masks)]
+    else:
+        with _stage("slot_scatter"):
+            x_seed, x_hops = _split_levels(feats, b, masks)
+            x_hops = [jnp.where(m[..., None], x, 0)
+                      for x, m in zip(x_hops, masks)]
     # balance-table seeds are already distinct per worker — skip the dedup
     # front end for the label fetch
     with _stage("labels"):

@@ -136,6 +136,14 @@ def cache_insert(
 
 # ------------------------------------------- the two inserts under fetch_rows
 
+def _former_insert_from(cache, ids, row_of, should, cfg):
+    """The copy above in ``cache_insert_from``'s form (the W=1 fetch's
+    inserts, which read only their winners' rows): it is handed every
+    offer's row."""
+    rows = row_of(jnp.arange(ids.shape[0], dtype=jnp.int32))
+    return cache_insert(cache, ids, rows, should, cfg)
+
+
 def fetch_states(mode: str, workers: int, former: bool, steps: int = 5,
                  seed: int = 0):
     """Cache states and insert counts after each of ``steps`` cached
@@ -166,7 +174,8 @@ def fetch_states(mode: str, workers: int, former: bool, steps: int = 5,
     state = jax.device_put(init_cache_state(cfg, d, workers), spec)
     rng = np.random.default_rng(seed + 1)
     history = []
-    patch = (mock.patch.object(generation, "cache_insert", cache_insert)
+    patch = (mock.patch.multiple(generation, cache_insert=cache_insert,
+                                 cache_insert_from=_former_insert_from)
              if former else contextlib.nullcontext())
     with patch:
         for _ in range(steps):

@@ -849,40 +849,74 @@ def test_cache_requires_cfg():
         fetch_rows(table, jnp.zeros(4, jnp.int32), "data", cache=cache)
 
 
+_PALLAS_FETCH = """
+import json
+import jax, jax.numpy as jnp, numpy as np
+from jax import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from repro.core.feature_cache import (CacheConfig, init_cache_state,
+                                      set_probe_impl)
+from repro.core.generation import fetch_rows
+from repro.kernels import ops
+
+calls = []
+kernel = ops.cache_probe_gather
+
+
+def counted(*a, **k):
+    calls.append(1)
+    return kernel(*a, **k)
+
+
+ops.cache_probe_gather = counted
+w, rows_pw, d, r = 2, 32, 8, 96
+cfg = CacheConfig(32, admit=1, assoc=2).validated()
+rng = np.random.default_rng(2)
+table = rng.standard_normal((w * rows_pw, d)).astype(np.float32)
+ids = rng.integers(0, w * rows_pw, (w, r)).astype(np.int32)
+mesh = Mesh(np.asarray(jax.devices()[:w]), ("data",))
+
+
+def worker(t, i, c):
+    c = jax.tree.map(lambda x: x[0], c)
+    out, c, _, cs = fetch_rows(t, i[0], "data", cache=c, cache_cfg=cfg)
+    return out[None], jax.tree.map(lambda x: x[None], c), cs.n_hits[None]
+
+
+set_probe_impl("pallas")
+run = jax.jit(shard_map(worker, mesh=mesh, in_specs=(P("data"),) * 3,
+                        out_specs=(P("data"),) * 3, check_vma=False))
+cache = jax.device_put(init_cache_state(cfg, d, w),
+                       NamedSharding(mesh, P("data")))
+_, cache, _ = run(jnp.asarray(table), jnp.asarray(ids), cache)
+got, _, hits = run(jnp.asarray(table), jnp.asarray(ids), cache)
+print(json.dumps({"same": bool(np.array_equal(np.asarray(got), table[ids])),
+                  "hits": int(np.asarray(hits).sum()),
+                  "kernel_calls": len(calls)}))
+"""
+
+
 def test_pallas_probe_impl_serves_cached_fetch():
-    """set_probe_impl('pallas') routes the production fetch front end
-    through the fused kernel — rows stay bit-identical to the table."""
-    from jax import shard_map
-    from jax.sharding import PartitionSpec as P
+    """set_probe_impl('pallas') routes the probe of the routed cached
+    fetch (two virtual CPU devices, in a subprocess) through the fused
+    kernel — rows stay bit-identical to the table."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
     from repro.core.feature_cache import set_probe_impl
-    from repro.launch.mesh import make_local_mesh
 
-    rng = np.random.default_rng(2)
-    n, d = 64, 8
-    table = jnp.asarray(rng.standard_normal((n, d)).astype(np.float32))
-    ids = jnp.asarray(rng.integers(0, n, 96, dtype=np.int32))
-    mesh = make_local_mesh(1, 1)
-
-    def worker(t, i, c):
-        out, c, fs, cs = fetch_rows(
-            t, i, "data", cache=squeeze_worker_axis(c),
-            cache_cfg=CacheConfig(32, admit=1, assoc=2))
-        return (out, restore_worker_axis(c),
-                jax.tree.map(lambda a: a[None], (fs, cs)))
-
-    set_probe_impl("pallas")
-    try:
-        run = jax.jit(shard_map(
-            worker, mesh=mesh, in_specs=(P(), P(), P("data")),
-            out_specs=(P(), P("data"), P("data")), check_vma=False))
-        cache = jax.tree.map(jnp.asarray, init_worker_caches(32, d, 1))
-        _, cache, _ = run(table, ids, cache)
-        got, cache, (fs, cs) = run(table, ids, cache)
-    finally:
-        set_probe_impl("jnp")
-    assert int(cs.n_hits[0]) > 0
-    np.testing.assert_array_equal(np.asarray(got),
-                                  np.asarray(table)[np.asarray(ids)])
+    src = Path(__file__).resolve().parent.parent / "src"
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=2")
+    env["PYTHONPATH"] = os.pathsep.join([str(src), env.get("PYTHONPATH", "")])
+    proc = subprocess.run([sys.executable, "-c", _PALLAS_FETCH],
+                          capture_output=True, text=True, timeout=600,
+                          env=env)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    got = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert got["same"] and got["hits"] > 0 and got["kernel_calls"] > 0, got
     with pytest.raises(ValueError):
         set_probe_impl("cuda")
 

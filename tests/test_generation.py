@@ -357,3 +357,195 @@ def test_stage_times_partition_the_generator_trace(graph, tmp_path):
     assert g["cache_insert"] > 0 and g["dedup"] > 0 and g["owner_fetch"] > 0
     parts = sum(g[s] for s in measured) + g["other"]
     assert abs(parts - g["gen"]) <= 0.05 * g["gen"], (parts, g)
+
+
+# --- the index-first W=1 cached fetch ----------------------------------------
+# At W=1 on the device store a cached fetch resolves each distinct id to a
+# source and gathers every slot's row once, into its level's shape.  It is
+# held, step by step, to the former fetch (``tests/_w1_fetch_ref.py``),
+# which built rows per distinct id and gathered them back to the slots.
+
+#: (cache mode, assoc, admit, feature width, form): ``train`` threads the
+#: state, ``stats`` is the autotuner's ``collect_stats`` form, ``frozen``
+#: the serve view on a warmed state, ``fetch`` calls ``fetch_rows`` alone
+W1_CASES = [
+    ("replicated", 1, 1, 3, "train"),
+    ("replicated", 4, 2, 130, "stats"),
+    ("replicated", 4, 1, 3, "fetch"),
+    ("sharded", 4, 2, 3, "train"),
+    ("sharded", 1, 2, 130, "stats"),
+    ("sharded", 4, 2, 130, "frozen"),
+    ("tiered", 4, 1, 130, "train"),
+    ("tiered", 1, 1, 3, "stats"),
+    ("tiered", 4, 2, 3, "stats"),
+    ("tiered", 4, 1, 3, "frozen"),
+    ("tiered", 1, 2, 130, "fetch"),
+]
+W1_NODES, W1_FANOUTS, W1_SEEDS, W1_STEPS = 300, (4, 3), 16, 6
+
+
+def _w1_cache_cfg(mode, assoc, admit):
+    from repro.core.feature_cache import CacheConfig
+    return CacheConfig(16, admit=admit, assoc=assoc, mode=mode,
+                       l1_rows=4 if mode == "tiered" else 0,
+                       l1_promote=1).validated()
+
+
+@pytest.fixture(scope="module")
+def w1_graph():
+    """A power-law graph with hubs (duplicate requests) whose nodes
+    ``i % 4 == 1`` have no out-edges, so their children are padding."""
+    from repro.graph.csr import CSRGraph
+    from repro.graph.synthetic import powerlaw_graph
+    g = powerlaw_graph(W1_NODES, avg_degree=6, n_hot=3, hot_degree=60,
+                       seed=1)
+    src, dst = g.edge_list()
+    keep = src % 4 != 1
+    return CSRGraph.from_edges(src[keep], dst[keep], W1_NODES)
+
+
+def _same_bits(a, b) -> bool:
+    a, b = np.asarray(a), np.asarray(b)
+    return a.dtype == b.dtype and a.shape == b.shape \
+        and a.tobytes() == b.tobytes()
+
+
+def _assert_same_batch(new, old, where):
+    for name in ("seeds", "labels", "n_dropped", "n_cache_hits",
+                 "n_cache_misses", "n_probe_demoted"):
+        assert _same_bits(getattr(new, name), getattr(old, name)), \
+            (where, name)
+    for level, (h, g, m, n, x, y) in enumerate(zip(
+            new.hops, old.hops, new.masks, old.masks, new.x_hops,
+            old.x_hops)):
+        assert _same_bits(h, g) and _same_bits(m, n), (where, level)
+        m = np.asarray(m)
+        # real slots bit for bit; padded ones zero of either sign
+        assert _same_bits(np.asarray(x)[m], np.asarray(y)[m]), (where, level)
+        assert np.array_equal(x, y), (where, level)
+        assert not np.any(np.asarray(x)[~m]), (where, level)
+    assert _same_bits(new.x_seed, old.x_seed), where
+
+
+def _assert_same_state(new, old, where):
+    for leaf_new, leaf_old in zip(jax.tree.leaves(new), jax.tree.leaves(old),
+                                  strict=True):
+        assert _same_bits(leaf_new, leaf_old), where
+
+
+def _assert_same_stats(new, old, where):
+    for part_new, part_old in zip(new, old, strict=True):
+        for name, a, b in zip(part_new._fields, part_new, part_old):
+            assert _same_bits(a, b), (where, type(part_new).__name__, name)
+
+
+def _w1_fetch_run(cfg, dim):
+    """The new and the former ``fetch_rows`` on Zipf-duplicated ids, a
+    state threaded through each, compared after every step."""
+    from jax import shard_map
+    from jax.sharding import Mesh, PartitionSpec as P
+    import _w1_fetch_ref
+    from repro.core.feature_cache import init_cache_state
+
+    mesh = Mesh(np.asarray(jax.devices()[:1]), ("data",))
+    rng = np.random.default_rng(dim)
+    table = jnp.asarray(rng.standard_normal((W1_NODES, dim))
+                        .astype(np.float32))
+
+    def run_with(fetch):
+        def worker(t, i, c):
+            out, c, fs, cs = fetch(t, i[0], "data", cache=c, cache_cfg=cfg)
+            return out[None], c, (fs, cs)
+        sq = generation.squeeze_worker_axis
+        rs = generation.restore_worker_axis
+        return jax.jit(shard_map(
+            lambda t, i, c: (lambda o, c, s: (o, rs(c), s))(
+                *worker(t, i, sq(c))),
+            mesh=mesh, in_specs=(P(), P("data"), P("data")),
+            out_specs=(P("data"), P("data"), P()), check_vma=False))
+
+    new_fn, old_fn = run_with(fetch_rows), run_with(_w1_fetch_ref.fetch_rows)
+    new = old = jax.tree.map(jnp.asarray, init_cache_state(cfg, dim, 1))
+    hits = 0
+    for step in range(W1_STEPS):
+        ids = jnp.asarray((rng.zipf(1.3, (1, 257)) % W1_NODES)
+                          .astype(np.int32))
+        out_new, new, stats_new = new_fn(table, ids, new)
+        out_old, old, stats_old = old_fn(table, ids, old)
+        assert _same_bits(out_new, out_old), step
+        _assert_same_state(new, old, step)
+        _assert_same_stats(stats_new, stats_old, step)
+        hits += int(stats_new[1].n_hits)
+    return hits, new
+
+
+@pytest.mark.parametrize("mode,assoc,admit,dim,form", W1_CASES)
+def test_w1_cached_fetch_matches_former_path(w1_graph, mode, assoc, admit,
+                                             dim, form):
+    """Over multi-step sequences the index-first fetch gives the batches
+    the former fetch gave (ids, masks, labels, counters, and rows: real
+    slots bit for bit, padded slots zero), leaves the cache state bit for
+    bit, and reports the same ``FetchStats`` and ``CacheStats``, in each
+    cache mode (tiered with its L1 promotion), the serve view and the
+    autotuner's form."""
+    import _w1_fetch_ref
+    from repro.core.balance import balance_table
+    from repro.core.partition import partition_edges
+    from repro.launch.mesh import make_mesh
+
+    cfg = _w1_cache_cfg(mode, assoc, admit)
+    if form == "fetch":
+        hits, state = _w1_fetch_run(cfg, dim)
+    else:
+        mesh = make_mesh((1,), ("data",))
+        rng = np.random.default_rng(dim + assoc)
+        feats = rng.standard_normal((W1_NODES, dim)).astype(np.float32)
+        labels = rng.integers(0, 5, W1_NODES)
+        gen_new, dev, state0 = generation.make_distributed_generator(
+            mesh, partition_edges(w1_graph, 1), feats, labels,
+            fanouts=W1_FANOUTS, cache_cfg=cfg,
+            collect_stats=form == "stats")
+        kw = dict(fanouts=W1_FANOUTS, cache_cfg=cfg,
+                  collect_stats=form == "stats")
+        gen_old = jax.jit(_w1_fetch_ref.make_former_generator_fn(mesh, **kw))
+        table = balance_table(np.arange(W1_NODES), 1, seed=0)
+        state = state0
+        if form == "frozen":
+            # warm the state as training would, then serve from it
+            for t in range(3):
+                _, state = gen_new(dev, jnp.asarray(
+                    table.per_worker[:, t * W1_SEEDS:(t + 1) * W1_SEEDS]),
+                    jax.random.PRNGKey(t), state)
+            serve = cfg.serve_view()
+            gen_new = jax.jit(generation.make_generator_fn(
+                mesh, fanouts=W1_FANOUTS, cache_cfg=serve))
+            gen_old = jax.jit(_w1_fetch_ref.make_former_generator_fn(
+                mesh, fanouts=W1_FANOUTS, cache_cfg=serve))
+        new = old = state
+        hits = 0
+        for step in range(W1_STEPS):
+            # recurring seeds and rngs, so hits and promotions happen
+            t = step % 3
+            seeds = jnp.asarray(
+                table.per_worker[:, t * W1_SEEDS:(t + 1) * W1_SEEDS])
+            rng_t = jax.random.PRNGKey(step % 2)
+            if form == "frozen":
+                out_new = gen_new(dev, seeds, rng_t, state)
+                out_old = gen_old(dev, seeds, rng_t, state)
+                _assert_same_batch(out_new, out_old, step)
+                hits += int(np.asarray(out_new.n_cache_hits).sum())
+                continue
+            out_new = gen_new(dev, seeds, rng_t, new)
+            out_old = gen_old(dev, seeds, rng_t, old)
+            _assert_same_batch(out_new[0], out_old[0], step)
+            new, old = out_new[1], out_old[1]
+            _assert_same_state(new, old, step)
+            if form == "stats":
+                _assert_same_stats(out_new[2], out_old[2], step)
+            hits += int(np.asarray(out_new[0].n_cache_hits).sum())
+            assert not np.asarray(out_new[0].masks[-1]).all(), \
+                "no padded slot in the batch"
+        state = new
+    assert hits > 0, "the cache served nothing: the cases test no hit"
+    if mode == "tiered" and form != "frozen":
+        assert (np.asarray(state.l1.keys) >= 0).any(), "nothing promoted"
